@@ -287,11 +287,11 @@ def run_ep_scaling(*, smoke: bool, out_dir: pathlib.Path) -> None:
     overlapped variant) at each mesh size the host exposes — launch under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU."""
     from benchmarks.common import time_fn, zipf_assignments
-    from repro.compat import set_mesh
     from repro.configs.base import MoEConfig
     from repro.core import dispatch_config, init_moe_params
     from repro.core.distributed import (a2a_send_rows, a2a_send_rows_static,
                                         apply_moe_ep)
+    from repro.launch.mesh import make_ep_mesh
 
     E, k, M, d, Tl, cf = 64, 2, 16, 32, 64, 2.0
     eps = [1, 2, 4]
@@ -326,14 +326,14 @@ def run_ep_scaling(*, smoke: bool, out_dir: pathlib.Path) -> None:
     curves = []
     steps = 2 if smoke else 8
     for ep in [e for e in eps if e <= ndev]:
-        mesh = jax.make_mesh((ep,), ("model",))
+        mesh = make_ep_mesh(ep)
         T = Tl * ep                       # weak scaling: Tl fixed per rank
         x = jax.random.normal(jax.random.key(1), (1, T, d))
         for policy in ("dynamic", "capacity_factor"):
             dcfg = dispatch_config(moe, executor="xla",
                                    schedule_policy=policy)
             for overlap in ((0, 2) if ep > 1 else (0,)):
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     fn = jax.jit(lambda p, x, o=overlap, c=dcfg:
                                  apply_moe_ep(p, x, c, overlap=o)[0])
                     t = time_fn(fn, params, x, warmup=1, iters=steps)

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, current_mesh, shard_map
 from repro.core.dispatch import MoEDispatchConfig
 from repro.execution import (combine_scale_rows, get_executor,
                              plan_dispatch)
@@ -315,7 +314,7 @@ def _ep_sharded_local(params, x_loc, cfg: MoEDispatchConfig, axis: str,
     and the capacity policy's drop set are decided over the FULL batch
     before chunking, so the overlap path is token-identical to the
     non-overlapped one."""
-    ep = axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     E, k, M = cfg.n_experts, cfg.top_k, cfg.block_m
     if E % ep:
         raise ValueError(f"n_experts={E} must divide over EP axis size {ep}")
@@ -408,7 +407,7 @@ def _ep_sharded_static_local(params, x_loc, cfg: MoEDispatchConfig,
     Every expert gets a static tile-aligned ``cap`` bucket; assignments
     beyond it are dropped REGARDLESS of ``cfg.schedule_policy`` (the
     historic policy bypass)."""
-    ep = axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     E, k, M = cfg.n_experts, cfg.top_k, cfg.block_m
     E_local = E // ep
     Tl, d = x_loc.shape
@@ -463,7 +462,7 @@ def _ep_sharded_static_local(params, x_loc, cfg: MoEDispatchConfig,
 def _ep_replicated_local(params, x_loc, cfg: MoEDispatchConfig, axis: str,
                          capacity_factor: float, stat_axes=None):
     """Per-rank body for token_layout='replicated' (decode)."""
-    ep = axis_size(axis)
+    ep = jax.lax.axis_size(axis)
     E, M = cfg.n_experts, cfg.block_m
     E_local = E // ep
     r = jax.lax.axis_index(axis)
@@ -554,10 +553,10 @@ def apply_moe_ep(params, x: jnp.ndarray, cfg: MoEDispatchConfig, *,
     plain GSPMD-land outside the shard_map.
     """
     capacity_factor = _resolve_capacity_factor(cfg, capacity_factor)
-    mesh = _current_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         raise RuntimeError("apply_moe_ep requires an active mesh "
-                           "(jax.set_mesh(...) or `with mesh:`)")
+                           "(jax.set_mesh(...))")
     if token_layout not in ("sharded", "sharded_static", "replicated"):
         raise ValueError(f"unknown token_layout {token_layout!r}")
     shape = x.shape
@@ -633,9 +632,11 @@ def apply_moe_ep(params, x: jnp.ndarray, cfg: MoEDispatchConfig, *,
     aux_spec = {"lb_loss": P(), "router_z": P()}
     if cfg.emit_stats:
         aux_spec.update({f"sched/{k}": P() for k in ScheduleStats._fields})
-    y, aux = shard_map(
+    # check_vma off: the replication checker cannot see through the EP
+    # bodies' collectives
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=(pspecs, in_spec),
-        out_specs=(out_spec, aux_spec))(routed, x)
+        out_specs=(out_spec, aux_spec), check_vma=False)(routed, x)
 
     if "shared" in params:
         sh = params["shared"]
@@ -653,7 +654,3 @@ def _axsize(mesh, axes) -> int:
         n *= mesh.shape[a]
     return n
 
-
-def _current_mesh():
-    """Concrete mesh from set_mesh(...) or a `with mesh:` block."""
-    return current_mesh()

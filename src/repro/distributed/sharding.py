@@ -109,6 +109,25 @@ def param_specs(params, cfg: ModelConfig, mesh: Mesh,
     return jax.tree_util.tree_map_with_path(rule, params)
 
 
+def ep_param_specs(params, cfg: ModelConfig, axis: str = "model"):
+    """Expert-parallel serving layout on a 1-D EP mesh: every routed
+    expert stack is split over ``axis`` on its expert dim (the layout the
+    EP dispatch's shard_map reads, so no step moves weights), everything
+    else is replicated.  Works on ShapeDtypeStructs."""
+    E = cfg.moe.n_experts if cfg.is_moe else None
+
+    def rule(path, leaf):
+        names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
+        stacked = int(bool(names) and names[0] in ("body", "shared"))
+        core = leaf.shape[stacked:]
+        if E is not None and len(core) >= 2 and core[0] == E \
+                and names[-1] != "router":
+            return P(*(None,) * stacked, axis)
+        return P()
+
+    return jax.tree_util.tree_map_with_path(rule, params)
+
+
 def opt_state_specs(param_spec_tree):
     """Adam moments share the param layout."""
     return {"m": param_spec_tree, "v": param_spec_tree,

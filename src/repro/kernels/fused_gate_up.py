@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.grouped_gemm import dequant_weight_block
 
 
@@ -131,7 +130,7 @@ def fused_gate_up(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((capacity, F), out_dtype or x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

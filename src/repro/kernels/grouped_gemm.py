@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.quantization.schemes import unpack_int4
 
 
@@ -145,7 +144,7 @@ def grouped_gemm(x: jnp.ndarray, w: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((capacity, N), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

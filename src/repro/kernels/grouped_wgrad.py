@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 
 def _kernel(block_expert_ref, block_active_ref,
@@ -86,7 +85,7 @@ def grouped_wgrad(x: jnp.ndarray, dy: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_experts, K, N),
                                        out_dtype or jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

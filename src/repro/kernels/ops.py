@@ -11,11 +11,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.quantization import QuantTensor, get_scheme
 from repro.scheduling import BlockSchedule
 from repro.kernels import fused_gate_up as _fgu
 from repro.kernels import grouped_gemm as _gg
+from repro.kernels import paged_attention as _paged
 from repro.kernels import permute as _perm
 from repro.kernels import router_topk as _router
 from repro.kernels import unpermute as _unperm
@@ -135,19 +137,35 @@ def router_topk(logits: jnp.ndarray, *, top_k: int, gating: str = "softmax",
         interpret=_interp(interpret))
 
 
+# A (rows, block_d) float32 tile of x (permute) or of the combine
+# accumulator (unpermute) stays VMEM-resident for a whole pass over the
+# padded rows; this bounds it well inside the default scoped-VMEM limit.
+_RESIDENT_BYTES = 2 << 20
+_ROW_BLOCK = 128                # padded rows moved per grid step
+
+
+def _resident_block_d(rows: int, d: int, target: int) -> int:
+    fit = max(128, _RESIDENT_BYTES // (4 * rows) // 128 * 128)
+    return pick_block(d, min(target, fit))
+
+
 def permute(x: jnp.ndarray, sched: BlockSchedule, *, block_d: int = 2048,
             interpret: bool | None = None) -> jnp.ndarray:
-    return _perm.permute(x, sched.src_tok,
-                         block_d=pick_block(x.shape[-1], block_d),
-                         interpret=_interp(interpret))
+    return _perm.permute(
+        x, sched.src_tok,
+        block_d=_resident_block_d(x.shape[0], x.shape[-1], block_d),
+        block_rows=pick_block(sched.capacity, _ROW_BLOCK, align=8),
+        interpret=_interp(interpret))
 
 
 def unpermute(y: jnp.ndarray, sched: BlockSchedule,
               weights: jnp.ndarray | None, *, block_d: int = 2048,
               interpret: bool | None = None) -> jnp.ndarray:
-    return _unperm.unpermute(y, sched.pos, weights,
-                             block_d=pick_block(y.shape[-1], block_d),
-                             interpret=_interp(interpret))
+    return _unperm.unpermute(
+        y, sched.src_tok, sched.pos, weights,
+        block_d=_resident_block_d(sched.pos.shape[0], y.shape[-1], block_d),
+        block_rows=pick_block(sched.capacity, _ROW_BLOCK, align=8),
+        interpret=_interp(interpret))
 
 
 def grouped_gemm(x: jnp.ndarray, w, sched: BlockSchedule,
@@ -207,3 +225,23 @@ def grouped_wgrad(x: jnp.ndarray, dy: jnp.ndarray, sched: BlockSchedule,
         block_k=pick_block(K, block_k), block_n=pick_block(N, block_n),
         interpret=_interp(interpret))
     return jnp.where((sched.counts > 0)[:, None, None], dw, 0.0)
+
+
+def paged_decode_attention(q, k_pool, v_pool, tables, kv_limit, *,
+                           scale: float, logit_softcap=None, q2=None,
+                           k2_pool=None):
+    """The fused paged decode kernel (kernels/paged_attention.py).
+
+    GSPMD cannot partition a Mosaic kernel, so under an active mesh (the
+    EP serving step, whose attention runs replicated) every device runs
+    it whole on replicated operands inside a ``shard_map``."""
+    def attend(q, k_pool, v_pool, tables, kv_limit, q2, k2_pool):
+        return _paged.paged_decode_attention(
+            q, k_pool, v_pool, tables, kv_limit, scale=scale,
+            logit_softcap=logit_softcap, q2=q2, k2_pool=k2_pool,
+            interpret=_interp(None))
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        attend = jax.shard_map(attend, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)
+    return attend(q, k_pool, v_pool, tables, kv_limit, q2, k2_pool)

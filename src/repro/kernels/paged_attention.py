@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30          # finite -inf stand-in (matches attention.py)
 
@@ -58,10 +57,10 @@ def _kernel(tables_ref, lim_ref, qpos_ref,        # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]                               # (G, D) pre-scaled
-    k = k_ref[0, :, 0, :]                         # (bs, D)
+    k = k_ref[0]                                  # (bs, D)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (G, bs)
     if q2_ref is not None:
-        s += jnp.dot(q2_ref[0, 0], k2_ref[0, :, 0, :].T,
+        s += jnp.dot(q2_ref[0, 0], k2_ref[0].T,
                      preferred_element_type=jnp.float32)
     if logit_softcap is not None:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
@@ -80,7 +79,7 @@ def _kernel(tables_ref, lim_ref, qpos_ref,        # scalar prefetch
     p = jnp.exp(s - m_new)
     p = jnp.where(ok, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
-    v = v_ref[0, :, 0, :]                         # (bs, Dv)
+    v = v_ref[0]                                  # (bs, Dv)
     acc_ref[...] = corr * acc_ref[...] + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -148,22 +147,29 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         raise ValueError("causal/window masks need q_pos (per-row query "
                          "positions)")
 
+    def kv_spec(depth):
+        # the pool is viewed as (n_blocks, bs, Hkv * depth) — a free
+        # reshape — so head h's (bs, depth) tile is a lane-aligned column
+        # block (depth % 128 == 0, or one head spanning the whole row) and
+        # no size-1 head block sits in the tiled minor dims
+        return pl.BlockSpec((1, bs, depth),
+                            lambda b, h, j, t, l, p: (t[b * nb + j], 0, h))
+
+    def pool(x):
+        return x.reshape(n_blocks, bs, -1)
+
     in_specs = [
         pl.BlockSpec((1, 1, G, D), lambda b, h, j, t, l, p: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, D),
-                     lambda b, h, j, t, l, p: (t[b * nb + j], 0, h, 0)),
-        pl.BlockSpec((1, bs, 1, Dv),
-                     lambda b, h, j, t, l, p: (t[b * nb + j], 0, h, 0)),
+        kv_spec(D), kv_spec(Dv),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [q, pool(k_pool), pool(v_pool)]
     if two:
         in_specs += [
             pl.BlockSpec((1, 1, G, D2),
                          lambda b, h, j, t, l, p: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D2),
-                         lambda b, h, j, t, l, p: (t[b * nb + j], 0, h, 0)),
+            kv_spec(D2),
         ]
-        operands += [q2, k2_pool]
+        operands += [q2, pool(k2_pool)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -191,7 +197,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

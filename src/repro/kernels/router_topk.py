@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 
 def _kernel(logits_ref, w_ref, i_ref, *, top_k: int, gating: str,
@@ -74,7 +73,7 @@ def router_topk(logits: jnp.ndarray, *, top_k: int, gating: str = "softmax",
                    pl.BlockSpec((block_t, top_k), lambda t: (t, 0))],
         out_shape=[jax.ShapeDtypeStruct((T, top_k), jnp.float32),
                    jax.ShapeDtypeStruct((T, top_k), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )

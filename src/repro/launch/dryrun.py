@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
 # count at first init). 512 placeholder host devices back the production
-# meshes: 16x16 single-pod, 2x16x16 multi-pod.
+# meshes: 16x16 single-pod, 2x16x16 multi-pod.  The dry run is a CPU-only
+# tool: it and the per-cell subprocesses it starts (which inherit this
+# environment) never open an accelerator, so it can run beside a process
+# that holds the chip.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -37,7 +41,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     from repro.analysis.hlo import collective_report
     from repro.configs import SHAPE_BY_NAME, cell_is_runnable, get_config
-    from repro.compat import cost_analysis, set_mesh
     from repro.distributed.ctx import use_rules
     from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import cell_inputs
@@ -74,7 +77,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         donate = (2,)
     t0 = time.time()
     try:
-        with set_mesh(mesh), use_rules(mesh, ci.rules):
+        with jax.set_mesh(mesh), use_rules(mesh, ci.rules):
             jitted = jax.jit(ci.step_fn, in_shardings=ci.in_shardings,
                              out_shardings=ci.out_shardings,
                              donate_argnums=donate)
@@ -83,7 +86,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         from repro.models.lm import group_structure
         _, _, n_groups, _ = group_structure(cfg)
@@ -182,7 +185,9 @@ def main() -> int:
                         print(r.stderr[-2000:], flush=True)
         return 1 if failures else 0
 
+    from repro.launch import use_compile_cache
     from repro.quantization import resolve_quant_cli
+    use_compile_cache()
     rec = run_cell(args.arch, args.shape, args.multi_pod,
                    save_hlo=args.save_hlo, accum=args.accum,
                    layout=args.layout, pin_grads=args.pin_grads,

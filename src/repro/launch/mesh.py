@@ -9,20 +9,32 @@ plain 1-device CPU.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with GSPMD-propagated (``Auto``) axes.
+
+    The partition rules (distributed/sharding.py) fix storage layouts and
+    leave compute shardings to propagation; explicit axes (jax's default)
+    would instead type every intermediate, and reject e.g. an embedding
+    gather whose table and tokens both name the 'data' axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; (2, 16, 16) = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh for CPU tests (requires forced host device count)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 # ----------------------------------------------------------------------
@@ -64,4 +76,4 @@ def make_ep_mesh(ep: int | None = None, axis: str = "model"):
     if len(jax.devices()) % n:
         raise ValueError(
             f"ep={n} does not divide the {len(jax.devices())}-device mesh")
-    return jax.make_mesh((n,), (axis,))
+    return _mesh((n,), (axis,))

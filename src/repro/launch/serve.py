@@ -11,9 +11,9 @@ dispatches the whole decode batch in one plan.
 import argparse
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     from repro.execution import available_executors
-    from repro.quantization import available_schemes, resolve_quant_cli
+    from repro.quantization import available_schemes
     from repro.sampling import available_samplers
     from repro.scheduling import available_policies
     from repro.serve.admission import available_admission_policies
@@ -138,8 +138,8 @@ def main():
                          "percentiles) as JSON")
     ap.add_argument("--device-trace", default=None, metavar="DIR",
                     help="bracket the run in a jax.profiler device trace "
-                         "written to DIR (best-effort: degrades to a "
-                         "warning when the profiler is unavailable)")
+                         "written to DIR (the run fails if the profiler "
+                         "cannot start or stop)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--distributed", action="store_true",
                     help="expert-parallel serving over every visible "
@@ -166,80 +166,75 @@ def main():
     ap.add_argument("--ep-decode-layout", default="replicated",
                     choices=("replicated", "sharded"),
                     help="EP token layout for decode steps")
-    args = ap.parse_args()
+    return ap
 
-    import contextlib
-    import os
 
-    if args.distributed and args.num_processes == 1:
-        # single-process fallback (CPU smoke): the EP collectives still
-        # need >1 device, so force a multi-device host platform BEFORE
-        # jax initializes
-        n_dev = args.ep_devices or 2
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{n_dev}").strip()
+def ep_mesh(args):
+    """The ``--distributed`` EP mesh over axis 'model' (joining the
+    ``jax.distributed`` group first when ``--num-processes`` > 1)."""
+    import jax
 
-    import numpy as np
+    from repro.launch.mesh import (init_distributed, make_ep_mesh,
+                                   multiprocess_compute_supported)
+    if args.num_processes > 1:
+        init_distributed(args.coordinator, args.num_processes,
+                         args.process_id)
+        if not multiprocess_compute_supported():
+            raise SystemExit(
+                "the active backend cannot run multi-process "
+                "computations (CPU): re-launch single-process with "
+                "--ep-devices N for a forced-host-device mesh")
+    mesh = make_ep_mesh(args.ep_devices, axis="model")
+    print(f"distributed serving: {jax.process_count()} process(es), "
+          f"EP mesh {mesh.devices.shape} over axis 'model', "
+          f"decode layout {args.ep_decode_layout}, overlap "
+          f"{'on' if args.ep_overlap else 'off'}")
+    return mesh
+
+
+def build_engine(args, cfg, *, obs):
+    """The launcher's serving engine for parsed ``args`` over ``cfg``.
+
+    Params are random from seed 0 (or restored from ``--ckpt-dir``);
+    routed experts are quantized under ``--quant``; the result is a
+    ``ServeEngine`` or, with ``--spec-draft``, a ``SpecEngine``.  Under
+    ``--distributed`` the EP mesh must already be set (``jax.set_mesh``):
+    params are then created in place on it, each device holding only
+    its share of every routed-expert stack."""
     import jax
 
     from repro.configs import get_config, reduced
     from repro.models import RunConfig, init_params
-    from repro.obs import (NOOP, Observability, device_trace, drop_summary,
-                           latency_summary)
+    from repro.quantization import resolve_quant_cli
     from repro.sampling import SamplingConfig
-    from repro.serve.engine import Request, ServeEngine
-    from repro.serve.frontend import ServingFrontend
-    from repro.serve.loadgen import make_virtual_obs, replay, synth_trace
-    from repro.spec import SpecEngine, make_draft_config
+    from repro.serve.engine import ServeEngine
+    from repro.spec import SpecEngine
 
-    cfg = get_config(args.arch)
-    if args.reduce:
-        cfg = reduced(cfg)
-    if not cfg.has_decode:
-        raise SystemExit(f"{args.arch} is encoder-only: no decode path")
+    def init():
+        return init_params(cfg, jax.random.key(0))
 
-    mesh_stack = contextlib.ExitStack()
+    shardings = None
     if args.distributed:
-        from repro.compat import set_mesh
-        from repro.launch.mesh import (init_distributed, make_ep_mesh,
-                                       multiprocess_compute_supported)
-        if args.num_processes > 1:
-            init_distributed(args.coordinator, args.num_processes,
-                             args.process_id)
-            if not multiprocess_compute_supported():
-                raise SystemExit(
-                    "the active backend cannot run multi-process "
-                    "computations (CPU): re-launch single-process with "
-                    "--ep-devices N for a forced-host-device mesh")
-        mesh = make_ep_mesh(args.ep_devices, axis="model")
-        mesh_stack.enter_context(set_mesh(mesh))
-        print(f"distributed serving: {jax.process_count()} process(es), "
-              f"EP mesh {mesh.devices.shape} over axis 'model', "
-              f"decode layout {args.ep_decode_layout}, overlap "
-              f"{'on' if args.ep_overlap else 'off'}")
-
-    params = init_params(cfg, jax.random.key(0))
+        from jax.sharding import NamedSharding
+        from repro.distributed.sharding import ep_param_specs
+        mesh = jax.sharding.get_mesh()
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            ep_param_specs(jax.eval_shape(init), cfg, axis="model"))
     if args.ckpt_dir:
         from repro.checkpoint.manager import CheckpointManager
         mgr = CheckpointManager(args.ckpt_dir)
-        state = mgr.restore(jax.eval_shape(lambda: {
-            "params": init_params(cfg, jax.random.key(0))}))
+        state = mgr.restore(jax.eval_shape(lambda: {"params": init()}),
+                            shardings=(None if shardings is None
+                                       else {"params": shardings}))
         params = state["params"]
+    else:
+        params = jax.jit(init, out_shardings=shardings)()
     quant = resolve_quant_cli(args.quant, args.quant_experts)
     if quant != "none" and cfg.is_moe:
         print(f"routed experts quantized under scheme {quant!r} "
               f"(serving layout)")
 
-    if args.loadgen:
-        clock, obs = make_virtual_obs(enabled=True)
-    else:
-        clock = None
-        obs = (Observability.memory()
-               if (args.trace or args.metrics_out or args.device_trace)
-               else NOOP)
     sampling = SamplingConfig(method=args.sampling,
                               temperature=args.temperature,
                               top_k=args.top_k, top_p=args.top_p,
@@ -275,6 +270,58 @@ def main():
               f"{args.spec_k + 1} positions per slot in one forward")
     else:
         engine = ServeEngine(cfg, params, **kw)
+    return engine
+
+
+def main():
+    args = build_parser().parse_args()
+
+    import contextlib
+    import os
+
+    if args.distributed and args.num_processes == 1:
+        # single-process fallback (CPU smoke): the EP collectives still
+        # need >1 device, so force a multi-device host platform BEFORE
+        # jax initializes
+        n_dev = args.ep_devices or 2
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                f"{flags} --xla_force_host_platform_device_count="
+                f"{n_dev}").strip()
+
+    import numpy as np
+    import jax
+
+    from repro.configs import get_config, reduced
+    from repro.launch import use_compile_cache
+    from repro.obs import (NOOP, Observability, device_trace, drop_summary,
+                           latency_summary)
+    from repro.serve.engine import Request
+    from repro.serve.frontend import ServingFrontend
+    from repro.serve.loadgen import make_virtual_obs, replay, synth_trace
+    from repro.spec import SpecEngine
+
+    use_compile_cache()
+
+    cfg = get_config(args.arch)
+    if args.reduce:
+        cfg = reduced(cfg)
+    if not cfg.has_decode:
+        raise SystemExit(f"{args.arch} is encoder-only: no decode path")
+
+    mesh_stack = contextlib.ExitStack()
+    if args.distributed:
+        mesh_stack.enter_context(jax.set_mesh(ep_mesh(args)))
+
+    if args.loadgen:
+        clock, obs = make_virtual_obs(enabled=True)
+    else:
+        clock = None
+        obs = (Observability.memory()
+               if (args.trace or args.metrics_out or args.device_trace)
+               else NOOP)
+    engine = build_engine(args, cfg, obs=obs)
     if engine.paged:
         print(f"paged KV cache: {engine.kv.n_blocks} blocks x "
               f"{engine.kv.block_size} tokens, prefix cache "

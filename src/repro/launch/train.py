@@ -15,11 +15,13 @@ any assigned architecture:
 """
 import os
 
-# overlap compute with collectives on real hardware (no-op on CPU)
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_latency_hiding_scheduler=true")
+# overlap compute with collectives on real hardware (no-op on CPU); added
+# to whatever LIBTPU_INIT_ARGS the machine already carries, never in its
+# place
+os.environ["LIBTPU_INIT_ARGS"] = " ".join(filter(None, (
+    os.environ.get("LIBTPU_INIT_ARGS", ""),
+    "--xla_tpu_enable_async_collective_fusion=true",
+    "--xla_tpu_enable_latency_hiding_scheduler=true")))
 
 import argparse  # noqa: E402
 
@@ -45,8 +47,8 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import get_config, reduced
-    from repro.compat import set_mesh
     from repro.distributed.ctx import use_rules
+    from repro.launch import use_compile_cache
     from repro.distributed.sharding import (activation_rules, batch_specs,
                                             param_specs)
     from repro.launch.mesh import make_debug_mesh, make_production_mesh
@@ -55,6 +57,7 @@ def main():
     from repro.train.loop import train
     from repro.train.step import init_train_state
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduce:
         cfg = reduced(cfg)
@@ -90,7 +93,7 @@ def main():
                      state_shardings=state_sh, batch_shardings=batch_sh)
 
     if mesh is not None:
-        with set_mesh(mesh), use_rules(mesh, rules):
+        with jax.set_mesh(mesh), use_rules(mesh, rules):
             out = run()
     else:
         out = run()

@@ -261,16 +261,13 @@ def attention_block(p, x: jnp.ndarray, *, n_heads: int, n_kv_heads: int,
                 # the window term inert — the fused call mirrors that
                 # exactly (window omitted) so both paths stay bitwise
                 # companions.
-                from repro.kernels.ops import _interp
-                from repro.kernels.paged_attention import \
-                    paged_decode_attention
+                from repro.kernels.ops import paged_decode_attention
                 B = q.shape[0]
                 G = n_heads // n_kv_heads
                 qf = q[:, 0].reshape(B, n_kv_heads, G, head_dim)
                 out = paged_decode_attention(
                     qf, new_k, new_v, block_tables, idx,
-                    scale=head_dim ** -0.5, logit_softcap=logit_softcap,
-                    interpret=_interp(None))
+                    scale=head_dim ** -0.5, logit_softcap=logit_softcap)
                 out = out.reshape(B, 1, n_heads, -1).astype(q.dtype)
                 out = out.reshape(B, 1, -1)
                 return jnp.dot(out, p["wo"].astype(x.dtype)), new_cache

@@ -129,8 +129,7 @@ def _mla_fused_paged_decode(p, q_nope, q_rope, ckv_pool, kr_pool, tables,
     (r+dr)^-0.5, in the same dtype and order) — but the scores run inside
     the fused Pallas kernel with ckv/kr as two scalar-prefetch-indexed
     score operands and ckv as the value.  Returns (B, 1, H, dv)."""
-    from repro.kernels.ops import _interp
-    from repro.kernels.paged_attention import paged_decode_attention
+    from repro.kernels.ops import paged_decode_attention
     dn, dr, dv = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
     r = mla.kv_lora_rank
     B = q_nope.shape[0]
@@ -146,7 +145,7 @@ def _mla_fused_paged_decode(p, q_nope, q_rope, ckv_pool, kr_pool, tables,
     kr4 = kr_pool[:, :, None, :]
     ctx = paged_decode_attention(
         q1, ckv4, ckv4, tables, kv_limit, scale=(r + dr) ** -0.5,
-        q2=q2, k2_pool=kr4, interpret=_interp(None))          # (B,1,H,r)
+        q2=q2, k2_pool=kr4)                                   # (B,1,H,r)
     return jnp.einsum("bthr,rhd->bthd", ctx.astype(q_nope.dtype), w_v)
 
 

@@ -18,8 +18,7 @@ tracing on vs off (tests/test_obs.py).  The default sink is
 
 The optional device-side view is ``device_trace()`` — a bracket around
 ``jax.profiler.start_trace``/``stop_trace`` producing XLA's own profile
-into a separate directory; it is best-effort (profiler availability
-varies by backend) and never fails the run.
+into a separate directory; a profiler failure fails the run.
 """
 from __future__ import annotations
 
@@ -127,26 +126,18 @@ NULL_TRACER = NullTracer()
 def device_trace(logdir: Optional[str]):
     """Optional ``jax.profiler`` bracket: profiles DEVICE-side execution
     into ``logdir`` (TensorBoard/XPlane format, independent of the host
-    span artifact).  No-op when ``logdir`` is falsy; best-effort —
-    profiler failures degrade to a warning, never a crashed serve run."""
+    span artifact).  No-op when ``logdir`` is falsy.  A profiler that
+    cannot start or stop raises: a run asked for a device trace never
+    finishes without one."""
     if not logdir:
         yield
         return
     import jax
-    started = False
-    try:
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception as e:                      # pragma: no cover - backend
-        print(f"[obs] device trace unavailable ({e!r}); continuing without")
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:              # pragma: no cover - backend
-                print(f"[obs] device trace stop failed ({e!r})")
+        jax.profiler.stop_trace()
 
 
 def validate_chrome_trace(doc: dict, *, required_names=()) -> dict:

@@ -240,7 +240,6 @@ from repro.core import apply_moe, dispatch_config, init_moe_params
 from repro.configs.base import MoEConfig
 from repro.core.distributed import apply_moe_ep
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh
 from repro.quantization import quantize_moe_params
 
 POLICIES = ("fixed", "dynamic", "capacity_factor")
@@ -269,7 +268,7 @@ for draw in range(6):
     dcfg = dispatch_config(moe, executor="xla", schedule_policy=pol,
                            emit_stats=True)
     y_ref, aux_ref = apply_moe(params, x, dcfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_ep = jax.jit(lambda p, x: apply_moe_ep(
             p, x, dcfg))(params, x)
     np.testing.assert_allclose(

@@ -28,14 +28,13 @@ from repro.core import apply_moe, dispatch_config, init_moe_params
 from repro.core.distributed import apply_moe_ep
 from repro.configs.base import MoEConfig
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 mesh = make_debug_mesh(data=2, model=4)
 moe = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32, n_shared_experts=1, block_m=8)
 params = init_moe_params(jax.random.key(0), moe, 16)
 x = jax.random.normal(jax.random.key(1), (4, 32, 16))
 dcfg = dispatch_config(moe, executor="xla")
 y_ref, _ = apply_moe(params, x, dcfg)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg, capacity_factor=8.0))(params, x)
     y_r, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg, token_layout="replicated"))(params, x)
 np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref), rtol=2e-4, atol=2e-4)
@@ -51,7 +50,6 @@ from repro.core import dispatch_config, init_moe_params
 from repro.core.distributed import apply_moe_ep
 from repro.configs.base import MoEConfig
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 mesh = make_debug_mesh(data=1, model=4)
 moe = MoEConfig(n_experts=4, top_k=1, d_ff_expert=16, block_m=8)
 params = init_moe_params(jax.random.key(0), moe, 8)
@@ -60,14 +58,14 @@ x = jax.random.normal(jax.random.key(1), (1, 64, 8))
 # under the padding-free sharded layout (they never dropped single-device)
 dcfg = dispatch_config(moe, executor="xla",
                        schedule_policy="capacity_factor")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tight, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg, capacity_factor=0.25))(params, x)
     loose, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg, capacity_factor=8.0))(params, x)
 t, l = np.asarray(tight), np.asarray(loose)
 dropped_rows = (np.abs(t).sum(-1) == 0).sum()
 assert dropped_rows > 0, "tight capacity must drop some tokens"
 # run twice -> identical (deterministic drop policy: lowest slot wins)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tight2, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg, capacity_factor=0.25))(params, x)
 np.testing.assert_array_equal(t, np.asarray(tight2))
 print("OK", int(dropped_rows))
@@ -83,7 +81,6 @@ from repro.core import apply_moe, dispatch_config, init_moe_params
 from repro.configs.base import MoEConfig
 from repro.core.distributed import apply_moe_ep
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh
 mesh = make_debug_mesh(data=1, model=4)
 moe = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32, block_m=8,
                 capacity_factor=0.5)
@@ -96,7 +93,7 @@ for pol in ("capacity_factor", "dynamic"):
         assert float(jnp.max(jnp.abs(
             y_ref - apply_moe(params, x, dcfg._replace(executor="dense"))[0]
         ))) > 1e-6, "cf=0.5 must actually drop tokens"
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_r, _ = jax.jit(lambda p, x: apply_moe_ep(
             p, x, dcfg, token_layout="replicated"))(params, x)
     np.testing.assert_allclose(np.asarray(y_r), np.asarray(y_ref),
@@ -117,7 +114,6 @@ from repro.core import apply_moe, dispatch_config, init_moe_params
 from repro.core.distributed import apply_moe_ep
 from repro.configs.base import MoEConfig
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 from repro.quantization import quantize_moe_params
 
 mesh = make_debug_mesh(data=2, model=4)
@@ -128,7 +124,7 @@ for sch in ("int8_expert", "int8_channel", "int4_packed"):
     qp = quantize_moe_params(params, sch)
     dcfg = dispatch_config(moe, executor="xla")
     y_ref, _ = apply_moe(qp, x, dcfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_sh, _ = jax.jit(lambda p, x: apply_moe_ep(
             p, x, dcfg, capacity_factor=8.0))(qp, x)
         y_r, _ = jax.jit(lambda p, x: apply_moe_ep(
@@ -152,7 +148,6 @@ from repro.train.step import init_train_state, make_train_step
 from repro.optim.adamw import OptConfig
 from repro.data.pipeline import make_batch
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 from repro.distributed.sharding import param_specs, batch_specs
 from repro.distributed.ctx import use_rules
 from repro.distributed.sharding import activation_rules
@@ -171,7 +166,7 @@ ss = {"params": ps, "opt": {"m": ps, "v": ps, "step": P()}}
 bs = batch_specs(cfg, mesh, "train", 8)
 ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                             is_leaf=lambda x: isinstance(x, P))
-with set_mesh(mesh), use_rules(mesh, activation_rules(cfg, mesh, "train", 8)):
+with jax.set_mesh(mesh), use_rules(mesh, activation_rules(cfg, mesh, "train", 8)):
     f = jax.jit(make_train_step(cfg, rc, opt, 1),
                 in_shardings=(ns(ss), ns(bs)), out_shardings=(ns(ss), None))
     s_sh, m_sh = f(jax.device_put(state, ns(ss)),
@@ -191,7 +186,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 m = CheckpointManager(r"{tmp_path}", async_save=False)
 state = {{"w": jnp.arange(32.0).reshape(8, 4), "step": jnp.int32(7)}}
 m.save(7, state)
@@ -211,14 +205,14 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.optim.compress import compressed_psum
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 mesh = make_debug_mesh(data=1, model=1, pod=8)
 g = jax.random.normal(jax.random.key(0), (8, 64))
 def body(gl):
     return compressed_psum(gl[0], "pod")[None]
-with set_mesh(mesh):
-    out = jax.jit(shard_map(body, mesh=mesh,
-        in_specs=P("pod", None), out_specs=P("pod", None)))(g)
+with jax.set_mesh(mesh):
+    out = jax.jit(jax.shard_map(body, mesh=mesh,
+        in_specs=P("pod", None), out_specs=P("pod", None),
+        check_vma=False))(g)
 ref = jnp.sum(g, 0)
 got = np.asarray(out)[0]
 rel = np.abs(got - np.asarray(ref)).max() / (np.abs(np.asarray(ref)).max())
@@ -234,7 +228,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.models.attention import flash_attention, combine_stats, naive_attention
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh, shard_map
 mesh = make_debug_mesh(data=2, model=4)
 B, S, H, D = 4, 64, 4, 16
 ks = jax.random.split(jax.random.key(0), 3)
@@ -250,11 +243,11 @@ def local(q, k, v):
                                 return_stats=True)
     out = combine_stats(acc, l, m, "model")
     return jnp.moveaxis(out, 3, 1).reshape(q.shape[0], 1, -1, out.shape[-1])
-with set_mesh(mesh):
-    f = jax.jit(shard_map(local, mesh=mesh,
+with jax.set_mesh(mesh):
+    f = jax.jit(jax.shard_map(local, mesh=mesh,
         in_specs=(P("data", None, None, None), P("data", "model", None, None),
                   P("data", "model", None, None)),
-        out_specs=P("data", None, None, None)))
+        out_specs=P("data", None, None, None), check_vma=False))
     out = f(q, k, v)
 ref = naive_attention(q, k, v, causal=False, kv_limit=pos)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-5, atol=3e-5)
@@ -278,7 +271,6 @@ from repro.core import apply_moe, dispatch_config, init_moe_params
 from repro.configs.base import MoEConfig
 from repro.core.distributed import apply_moe_ep
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh
 moe = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32, block_m=8,
                 capacity_factor=0.5)
 params = init_moe_params(jax.random.key(0), moe, 16)
@@ -289,7 +281,7 @@ for pol in ("fixed", "dynamic", "capacity_factor"):
     dcfg = dispatch_config(moe, executor="xla", schedule_policy=pol,
                            emit_stats=True)
     y_ref, aux_ref = apply_moe(params, x, dcfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         run = lambda **kw: jax.jit(lambda p, x: apply_moe_ep(
             p, x, dcfg, **kw))(params, x)
         y_sh, aux_sh = run()
@@ -325,7 +317,6 @@ from repro.core import dispatch_config, init_moe_params
 from repro.configs.base import MoEConfig
 from repro.core.distributed import apply_moe_ep
 from repro.launch.mesh import make_debug_mesh
-from repro.compat import set_mesh
 mesh = make_debug_mesh(data=2, model=4)
 moe = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32, block_m=8,
                 capacity_factor=0.5)
@@ -333,7 +324,7 @@ params = init_moe_params(jax.random.key(0), moe, 16)
 x = jax.random.normal(jax.random.key(1), (4, 32, 16))
 for pol in ("fixed", "dynamic", "capacity_factor"):
     dcfg = dispatch_config(moe, executor="xla", schedule_policy=pol)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y0, _ = jax.jit(lambda p, x: apply_moe_ep(p, x, dcfg))(params, x)
         for n_micro in (2, 4):
             y1, _ = jax.jit(lambda p, x, n=n_micro: apply_moe_ep(
@@ -358,14 +349,13 @@ from repro.obs import Observability
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.distributed import DistributedServeLoop
 from repro.launch.mesh import make_ep_mesh
-from repro.compat import set_mesh
 cfg = reduced(get_config("moonshot-v1-16b-a3b"))
 from repro.models import init_params
 params = init_params(cfg, jax.random.key(0))
 rc = RunConfig(q_chunk=64, kv_chunk=64, ep=True, moe_stats=True,
                schedule_policy="capacity_factor", capacity_factor=0.5)
 obs = Observability.memory()
-with set_mesh(make_ep_mesh(2)):
+with jax.set_mesh(make_ep_mesh(2)):
     eng = ServeEngine(cfg, params, slots=2, capacity=32, rc=rc, obs=obs)
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 5)
@@ -447,3 +437,27 @@ def test_capacity_factor_resolution_order():
     assert _resolve_capacity_factor(cfg, 0.25) == 0.25
     cfg2 = dispatch_config(moe, executor="xla", capacity_factor=3.0)
     assert _resolve_capacity_factor(cfg2, None) == 3.0
+
+
+def test_ep_param_specs_split_only_routed_expert_stacks():
+    """The EP serving layout: each routed-expert stack is split on its
+    expert dim (after the layer-stack dim when stacked), everything else
+    (router, shared experts, attention, embeddings) is replicated."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from repro.configs import get_config, reduced
+    from repro.distributed.sharding import ep_param_specs
+    from repro.models import init_params
+    cfg = reduced(get_config("moonshot-v1-16b-a3b")).replace(n_layers=3)
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    specs = ep_param_specs(shapes, cfg, axis="model")
+    moe = specs["body"]["b0"]["moe"]
+    for name in ("w_gate", "w_up", "w_down"):
+        assert moe[name] == P(None, "model"), (name, moe[name])
+        assert moe["shared"][name] == P(), name
+    assert moe["router"] == P()
+    assert specs["embed"] == P() and specs["head"] == P()
+    assert specs["prefix"][0]["ffn"]["w_gate"] == P()
+    split = [s for s in jax.tree.leaves(
+        specs, is_leaf=lambda x: isinstance(x, P)) if s != P()]
+    assert len(split) == 3, split

@@ -340,3 +340,33 @@ def test_train_loop_emits_spans_and_metrics():
     assert obs.metrics.counter_value("train/steps_logged") > 0
     hists = {h["name"] for h in obs.metrics.snapshot()["histograms"]}
     assert any(n.startswith("train/") for n in hists)
+
+
+# ---------------------------------------------------------------------------
+# Device trace bracket
+# ---------------------------------------------------------------------------
+def test_device_trace_is_a_noop_without_a_logdir(monkeypatch):
+    from repro.obs import device_trace
+
+    def boom(*a, **k):
+        raise AssertionError("profiler touched")
+    monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    with device_trace(None):
+        pass
+
+
+@pytest.mark.parametrize("phase", ["start", "stop"])
+def test_device_trace_raises_when_the_profiler_fails(monkeypatch, tmp_path,
+                                                     phase):
+    """A run asked for a device trace must not finish without one."""
+    from repro.obs import device_trace
+
+    def fail(*a, **k):
+        raise RuntimeError(f"profiler {phase} failed")
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        fail if phase == "start" else lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        fail if phase == "stop" else lambda: None)
+    with pytest.raises(RuntimeError, match=f"profiler {phase} failed"):
+        with device_trace(str(tmp_path)):
+            pass

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis
 
 from repro.analysis.hlo import collective_report, parse_collectives
 
@@ -58,7 +57,7 @@ def test_scan_body_counted_once():
     w = jnp.ones((64, 64), jnp.float32)
     f = lambda x: jax.lax.scan(lambda c, _: (c @ w, None), x, None,
                                length=10)[0]
-    ca = cost_analysis(jax.jit(f).lower(jnp.ones((64, 64))).compile())
+    ca = jax.jit(f).lower(jnp.ones((64, 64))).compile().cost_analysis()
     one = 2 * 64 ** 3
     assert ca["flops"] == pytest.approx(one, rel=0.01), \
         "premise broken: update §Roofline methodology"
@@ -83,7 +82,7 @@ def test_analytic_flops_vs_unrolled_compile():
     def step(p, b):
         return jax.grad(lambda p: loss_fn(p, cfg, rc, b)[0])(p)
 
-    ca = cost_analysis(jax.jit(step).lower(params, batch).compile())
+    ca = jax.jit(step).lower(params, batch).compile().cost_analysis()
     shape = ShapeConfig("t", S, B, "train")
     cost = cell_cost(cfg, shape, chips=1, accum=1, remat=False)
     # analytic dispatch_flops excludes remat here; unrolled grad compile

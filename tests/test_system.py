@@ -2,6 +2,7 @@
 with the SAME weights, exercising the full stack (data pipeline, loop,
 optimizer, checkpoint manager, serving engine) in one flow."""
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -46,3 +47,24 @@ def test_train_checkpoint_serve_roundtrip(tmp_path):
     loss, _ = loss_fn(params, cfg, RC,
                       {k: jnp.asarray(v) for k, v in batch.items()})
     assert float(loss) < 0.8 * np.log(cfg.vocab_size)
+
+
+@pytest.mark.parametrize("placed", [False, True])
+def test_compile_cache_goes_to_the_env_dir_or_one_fixed_path(monkeypatch,
+                                                             placed):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to jax; without it the launch
+    entry points use one fixed directory inside the checkout."""
+    import pathlib
+    from repro import launch
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.update({k: v}))
+    seen = {}
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        launch.use_compile_cache()
+        assert seen == {}
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        launch.use_compile_cache()
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert seen == {"jax_compilation_cache_dir": str(repo / ".jax_cache")}

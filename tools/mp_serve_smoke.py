@@ -14,6 +14,11 @@ multi-device host mesh, same per-host admission + global-step code.
 Either way the smoke FAILS unless a distributed serve run completes all
 its requests.
 
+This is a CPU-only tool: every child runs with ``JAX_PLATFORMS=cpu``.
+A chip belongs to one process at a time, so children that each opened
+the accelerator would fail or hang; the chip's own EP path is
+``chip_smoke.py --chips 4``, which drives all devices from one process.
+
 Usage:
     PYTHONPATH=src python tools/mp_serve_smoke.py [--processes 2]
         [--port 12377]
@@ -32,7 +37,7 @@ LAUNCH = [sys.executable, "-m", "repro.launch.serve",
 
 def _env():
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env
