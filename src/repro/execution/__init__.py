@@ -8,7 +8,7 @@ from repro.execution.base import (DispatchPlan, Executor,  # noqa: F401
                                   available_executors, combine_scale_rows,
                                   execute, get_executor, plan_dispatch,
                                   plan_schedule, register_executor,
-                                  router_aux_losses, set_plan_hook)
+                                  router_aux_losses)
 from repro.execution.dense import DenseExecutor  # noqa: F401
 from repro.execution.pallas import PallasExecutor  # noqa: F401
 from repro.execution.xla import (XlaExecutor, fused_gate_up_xla,  # noqa: F401

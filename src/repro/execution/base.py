@@ -203,27 +203,6 @@ def available_executors():
 
 
 # ----------------------------------------------------------------------
-# Plan-stats hook (observability)
-# ----------------------------------------------------------------------
-# Called once per TRACED plan construction with host-static facts
-# (token count, executor, policy).  plan_dispatch python-executes only
-# while jax traces, so the hook fires exactly at (re)compile events —
-# repro.obs wires it to a `moe/plans_traced` counter and a `plan_trace`
-# span instant.  Process-global by design (one observability bundle per
-# process); the default None costs a single identity check per trace.
-_PLAN_HOOK: Optional[Callable[..., None]] = None
-
-
-def set_plan_hook(hook: Optional[Callable[..., None]]):
-    """Install ``hook(tokens=..., executor=..., policy=...)``; returns
-    the previous hook so callers (tests, short-lived engines) can
-    restore it."""
-    global _PLAN_HOOK
-    prev, _PLAN_HOOK = _PLAN_HOOK, hook
-    return prev
-
-
-# ----------------------------------------------------------------------
 # The two API entry points
 # ----------------------------------------------------------------------
 def plan_dispatch(x: jnp.ndarray, w_router: jnp.ndarray, cfg, *,
@@ -237,9 +216,6 @@ def plan_dispatch(x: jnp.ndarray, w_router: jnp.ndarray, cfg, *,
     rank-local layouts from ``plan.indices`` instead.
     """
     ex = get_executor(cfg.executor)
-    if _PLAN_HOOK is not None:
-        _PLAN_HOOK(tokens=int(x.shape[0]), executor=str(cfg.executor),
-                   policy=str(cfg.schedule_policy))
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32))
     weights, indices = ex.route(logits, cfg)
     aux = router_aux_losses(logits, indices, cfg)

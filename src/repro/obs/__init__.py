@@ -1,17 +1,20 @@
 """Serve-path observability: metrics registry + span tracer + latency.
 
-One bundle (DESIGN.md §10) threads through the serve engine, the jitted
-step constructors, the paged KV pool, the executor plan hook, and the
+One bundle (DESIGN.md §10) threads through the serve engine and its
+front end, the jitted step constructors, the paged KV pool, and the
 train loop:
 
 * ``Observability.metrics`` — the labeled counter/gauge/histogram sink
   (obs/metrics.py) absorbing the previously-scattered telemetry:
   per-plan ``sched/*`` stats at retirement, ``PagedKVCache.stats()``
   per step, admission/drop counts, quantized expert payload bytes.
-* ``Observability.tracer`` — Chrome-trace spans of the step timeline
-  (obs/trace.py): admit / prefix probe / assemble / forward dispatch /
-  host sync / retire, plus instants for recompiles, slow steps, block
-  evictions and compactions.
+* ``Observability.tracer`` — spans of the step timeline (obs/trace.py):
+  the front end's poll / scheduling pass / admit / prefix probe / step /
+  assemble / input transfers / forward dispatch / host sync /
+  postprocess / streaming / prefix registration / retire, plus instants
+  for recompiles, slow steps, block evictions and compactions.  The
+  spans are kept as Chrome-trace JSON and, while a ``jax.profiler``
+  trace runs, also land in that profile beside the device's programs.
 * ``Observability.straggler`` — the PR 2 ``StragglerMonitor`` wired as
   a serve-side slow-step detector (injectable clock): flagged steps
   become ``serve/slow_steps`` counts and ``slow_step`` trace instants.
@@ -53,10 +56,12 @@ __all__ = [
 class Observability:
     """Metrics + tracer + optional straggler monitor, one shared clock.
 
-    ``enabled`` is False only for the null bundle: call sites that would
-    do real work to FEED a sink (walking a params tree for byte counts,
-    converting a stats dict) gate on it; plain span/counter calls do not
-    — the null sinks absorb those for free."""
+    ``metered`` is True when the metrics sink is a real one: call sites
+    that would do real work to FEED it (walking a params tree for byte
+    counts, reading the pool's stats, converting a stats dict) gate on
+    it, so a bundle that only traces does none of that work; plain
+    span/counter calls do not gate — the null sinks absorb those for
+    free."""
 
     def __init__(self, metrics: Optional[MetricsRegistry] = None,
                  tracer: Optional[SpanTracer] = None,
@@ -66,9 +71,7 @@ class Observability:
         self.tracer = NULL_TRACER if tracer is None else tracer
         self.straggler = straggler
         self.clock = clock
-        self.enabled = not (self.metrics is NULL_METRICS
-                            and self.tracer is NULL_TRACER
-                            and straggler is None)
+        self.metered = self.metrics is not NULL_METRICS
 
     @classmethod
     def memory(cls, clock: Callable[[], float] = time.perf_counter,
@@ -110,15 +113,6 @@ class Observability:
         the traced computation."""
         self.metrics.inc("serve/recompiles", kind=kind)
         self.tracer.instant("recompile", kind=kind, **static)
-
-    def on_plan(self, *, tokens: int, executor: str, policy: str) -> None:
-        """Executor plan-stats hook (execution/base.py): one call per
-        TRACED ``plan_dispatch`` — counts how many distinct plan shapes
-        each MoE layer compiled and tags them by backend/policy."""
-        self.metrics.inc("moe/plans_traced", executor=executor,
-                         policy=policy)
-        self.tracer.instant("plan_trace", tokens=tokens,
-                            executor=executor, policy=policy)
 
 
 NOOP = Observability()

@@ -1,24 +1,36 @@
-"""Step-timeline span tracer emitting Chrome-trace / Perfetto JSON.
+"""Step-timeline span tracer: Chrome-trace JSON and the profiler's trace.
 
 ``SpanTracer`` records HOST-side wall-clock spans of the serve/train
-loops — admission, prefix-hash probe, step assembly, the jitted forward
-dispatch, the one-per-step host sync, retirement — plus instant events
-for the things that happen *to* the loop: recompiles (first call at a new
-shape), straggler-flagged slow steps, block evictions/compactions.  The
+loops — the front end's poll, the scheduling pass, admission, prefix-hash
+probe, step assembly and its input transfers, the jitted forward
+dispatch, the one-per-step host sync, postprocessing, token streaming,
+prefix registration, retirement — plus instant events for the things
+that happen *to* the loop: recompiles (first call at a new shape),
+straggler-flagged slow steps, block evictions/compactions.  The in-memory
 artifact (``results/trace/*.json``) loads directly in ``chrome://tracing``
 / https://ui.perfetto.dev.
 
-Overhead contract (DESIGN.md §10): a span is two ``clock()`` calls and
-one dict append; nothing here touches a device value, inserts an op into
-a traced computation, or forces a sync — the forward span measures
-DISPATCH cost (jax is async), the host_sync span measures where blocking
-actually happens.  Greedy tokens are asserted bitwise-identical with
-tracing on vs off (tests/test_obs.py).  The default sink is
-``NullTracer`` (shared no-op context manager, no state).
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+and arguments (the step span a ``StepTraceAnnotation`` with its
+``step_num``) for as long as it is open.  While a profiler trace runs
+(``device_trace()``, ``launch/serve --device-trace``), the engine's spans
+therefore land in the profile on the device's own clock, beside the
+device's programs, and each idle gap of the device can be charged to the
+host work around it.  With no profiler running an annotation is a
+no-op of about a microsecond.
 
-The optional device-side view is ``device_trace()`` — a bracket around
-``jax.profiler.start_trace``/``stop_trace`` producing XLA's own profile
-into a separate directory; a profiler failure fails the run.
+Overhead contract (DESIGN.md §10): a span is two ``clock()`` calls, one
+annotation and one dict append; nothing here touches a device value,
+inserts an op into a traced computation, or forces a sync — the forward
+span measures DISPATCH cost (jax is async), the host_sync span measures
+where blocking actually happens.  Greedy tokens are asserted
+bitwise-identical with tracing on vs off (tests/test_obs.py).  The
+default sink is ``NullTracer`` (shared no-op context manager, no state,
+no annotation).
+
+``device_trace()`` is the bracket around ``jax.profiler.start_trace`` /
+``stop_trace`` that writes that profile; a profiler failure fails the
+run.
 """
 from __future__ import annotations
 
@@ -27,21 +39,27 @@ import json
 import time
 from typing import Callable, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 
 class _Span:
-    """Context manager for one complete ("ph": "X") event."""
-    __slots__ = ("tracer", "name", "args", "t0")
+    """Context manager for one complete ("ph": "X") event, mirrored into
+    the profiler's trace by ``annotation`` while it is open."""
+    __slots__ = ("tracer", "name", "args", "annotation", "t0")
 
-    def __init__(self, tracer, name, args):
+    def __init__(self, tracer, name, args, annotation):
         self.tracer = tracer
         self.name = name
         self.args = args
+        self.annotation = annotation
 
     def __enter__(self):
         self.t0 = self.tracer.clock()
+        self.annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self.annotation.__exit__(*exc)
         t1 = self.tracer.clock()
         self.tracer._emit(self.name, "X", self.t0, dur=t1 - self.t0,
                           args=self.args)
@@ -77,7 +95,14 @@ class SpanTracer:
 
     def span(self, name: str, **args) -> _Span:
         """``with tracer.span("serve/forward", tokens=T): ...``"""
-        return _Span(self, name, args or None)
+        return _Span(self, name, args or None, TraceAnnotation(name, **args))
+
+    def step(self, name: str, step: int, **args) -> _Span:
+        """The span of one whole loop step: recorded with ``step`` among
+        its arguments, and a ``StepTraceAnnotation`` (``step_num``) in
+        the profiler's trace, which groups the profile by step."""
+        return _Span(self, name, {"step": step, **args},
+                     StepTraceAnnotation(name, step_num=step, **args))
 
     def instant(self, name: str, **args) -> None:
         self._emit(name, "i", self.clock(), args=args or None)
@@ -101,15 +126,18 @@ class SpanTracer:
 
 
 class NullTracer(SpanTracer):
-    """Default sink: ``span()`` hands back one shared do-nothing context
-    manager and ``instant``/``save`` are empty — no clock reads, no
-    allocation, no file."""
+    """Default sink: ``span()``/``step()`` hand back one shared do-nothing
+    context manager and ``instant``/``save`` are empty — no clock reads,
+    no annotation, no allocation, no file."""
 
     def __init__(self):
         super().__init__(clock=lambda: 0.0)
         self._null = contextlib.nullcontext()
 
     def span(self, name, **args):
+        return self._null
+
+    def step(self, name, step, **args):
         return self._null
 
     def instant(self, name, **args):
@@ -125,10 +153,11 @@ NULL_TRACER = NullTracer()
 @contextlib.contextmanager
 def device_trace(logdir: Optional[str]):
     """Optional ``jax.profiler`` bracket: profiles DEVICE-side execution
-    into ``logdir`` (TensorBoard/XPlane format, independent of the host
-    span artifact).  No-op when ``logdir`` is falsy.  A profiler that
-    cannot start or stop raises: a run asked for a device trace never
-    finishes without one."""
+    into ``logdir`` (TensorBoard/XPlane format), with the spans of every
+    ``SpanTracer`` that runs inside it on the host's line of the same
+    profile.  No-op when ``logdir`` is falsy.  A profiler that cannot
+    start or stop raises: a run asked for a device trace never finishes
+    without one."""
     if not logdir:
         yield
         return

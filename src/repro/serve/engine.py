@@ -30,20 +30,24 @@ pre-paging contiguous engine — same public behavior, selected
 automatically (or force with ``kv_block_size=0``).
 
 **Observability (DESIGN.md §10).**  The engine accepts an
-``Observability`` bundle (repro.obs; default: null sinks): every step is
-bracketed into Chrome-trace spans (assemble / forward dispatch / the one
-host sync / postprocess), admission and the prefix-hash probe are
-spanned, recompile events fire from inside the jitted bodies at trace
-time, per-step KV-pool occupancy lands as gauges, the PR 2
-``StragglerMonitor`` flags slow steps, and retirement absorbs the
-request's ``sched/*`` plan stats into histograms.  All of it is
-host-side wall-clock over already-materialized values — NO device op is
-added, so greedy tokens are bitwise-identical with observability on or
-off (asserted in tests/test_obs.py).  Per-request latency accounting
-(``lat/*`` in ``Request.stats``: queue wait, TTFT, TPOT, E2E — the
-MoE-Inference-Bench axes) is always on; it costs a handful of host clock
-reads per step.  The ``lat/*`` + ``serve/*`` key schema is identical
-between the paged and contiguous engines.
+``Observability`` bundle (repro.obs; default: null sinks): every piece
+of per-step host work is a span, nested as the calls nest — the
+scheduling pass (admission, the prefix-hash probe, preemption), and
+inside the step span the assembly and its input transfers, the forward
+dispatch, the one host sync, postprocessing with token streaming,
+prefix registration and retirement, and the step's bookkeeping.  While
+a profiler trace runs the spans land in it too.  Recompile events fire
+from inside the jitted bodies at trace time, per-step KV-pool occupancy
+lands as gauges, the ``StragglerMonitor`` flags slow steps, and
+retirement absorbs the request's ``sched/*`` plan stats into
+histograms.  All of it is host-side wall-clock over already-materialized
+values — NO device op is added, so greedy tokens are bitwise-identical
+with observability on or off (asserted in tests/test_obs.py).
+Per-request latency accounting (``lat/*`` in ``Request.stats``: queue
+wait, TTFT, TPOT, E2E — the MoE-Inference-Bench axes) is always on; it
+costs a handful of host clock reads per step.  The ``lat/*`` +
+``serve/*`` key schema is identical between the paged and contiguous
+engines.
 """
 from __future__ import annotations
 
@@ -55,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.execution.base import set_plan_hook
 from repro.models.lm import RunConfig, init_cache, swap_cache_slots
 from repro.obs import NOOP, RequestTimeline
 from repro.sampling import SamplingConfig
@@ -149,9 +152,10 @@ class ServeEngine:
         self.dropped: List[Request] = []
         self._admission_name = admission
         self._admission = get_admission(admission)
-        # streaming hook: called as ``on_token(req, tok)`` at the moment
-        # the step's ONE host sync retires a token into ``req.out`` — the
-        # front-end fans it out to per-request callbacks.  Purely host-side
+        # streaming hook: called as ``on_token(req, tok)`` for each token
+        # the step's ONE host sync retired into ``req.out``, once the
+        # step's tokens are in (``serve/stream``) — the front-end fans it
+        # out to per-request callbacks.  Purely host-side
         # (fires on already-materialized ints), so streaming adds no
         # device syncs and tokens are bitwise-identical to batch run().
         self.on_token = None
@@ -198,23 +202,16 @@ class ServeEngine:
             self._decode_steps: Dict[int, object] = {}
             self._swap = jax.jit(swap_cache_slots)
 
-        if self.obs.enabled:
-            # plan-stats hook: every TRACED plan_dispatch reports its
-            # token count/backend/policy (process-global; last bundle
-            # installed wins — one observability bundle per process)
-            set_plan_hook(self.obs.on_plan)
-            if self.rc.quant != "none" and cfg.is_moe:
-                # the decode-dominant cost this serving config moves per
-                # expert gather: compressed payload bytes under the scheme
-                from repro.quantization import QuantTensor
-                leaves = jax.tree.leaves(
-                    self.params,
-                    is_leaf=lambda x: isinstance(x, QuantTensor))
-                self.obs.metrics.set_gauge(
-                    "serve/quant_expert_bytes",
-                    sum(l.nbytes for l in leaves
-                        if isinstance(l, QuantTensor)),
-                    scheme=self.rc.quant)
+        if self.obs.metered and self.rc.quant != "none" and cfg.is_moe:
+            # the decode-dominant cost this serving config moves per
+            # expert gather: compressed payload bytes under the scheme
+            from repro.quantization import QuantTensor
+            leaves = jax.tree.leaves(
+                self.params, is_leaf=lambda x: isinstance(x, QuantTensor))
+            self.obs.metrics.set_gauge(
+                "serve/quant_expert_bytes",
+                sum(l.nbytes for l in leaves if isinstance(l, QuantTensor)),
+                scheme=self.rc.quant)
 
     # ------------------------------------------------------------------
     def _batch(self, toks):
@@ -253,13 +250,23 @@ class ServeEngine:
         self.obs.metrics.inc("serve/admitted")
         return True
 
-    def _emit(self, req: Request, tok: int, t: float) -> None:
+    def _emit(self, req: Request, tok: int, t: float,
+              emitted: list) -> None:
         """Retire one token into ``req.out`` (post-host-sync): latency
-        stamp + the streaming hook, zero additional device work."""
+        stamp, zero additional device work; ``emitted`` collects it for
+        the step's ``_stream``."""
         req.out.append(tok)
         self._timing[req.rid].on_token(t)
-        if self.on_token is not None:
-            self.on_token(req, tok)
+        emitted.append((req, tok))
+
+    def _stream(self, emitted: list) -> None:
+        """Hand the tokens a step produced to the streaming hook, in the
+        order they were emitted."""
+        if self.on_token is None or not emitted:
+            return
+        with self.obs.tracer.span("serve/stream", tokens=len(emitted)):
+            for req, tok in emitted:
+                self.on_token(req, tok)
 
     def _admit(self, req: Request, t_admit: float) -> None:
         s = self.n_active
@@ -333,7 +340,9 @@ class ServeEngine:
             # first token: TTFT stamp + stream.  A resume's prefill output
             # is a token the request already streamed (the replay's last
             # logits re-predict out[-1]) — recompute only, never re-emit.
-            self._emit(req, first, self._clock())
+            emitted = []
+            self._emit(req, first, self._clock(), emitted)
+            self._stream(emitted)
         if resumed:
             self.n_resumed += 1
             self.obs.metrics.inc("serve/resumed")
@@ -369,11 +378,12 @@ class ServeEngine:
             return 0
         obs, i_step = self.obs, self._step_idx
         obs.step_begin(i_step)
-        with obs.tracer.span("serve/step", step=i_step, active=n):
+        with obs.tracer.step("serve/step", i_step, active=n):
             # assemble the step's token batch: per slot either its decode
             # token or the next chunk of its prompt
             with obs.tracer.span("serve/assemble"):
                 rows = []                   # (slot, token, position, kind)
+                n_decode = n_final = 0
                 for s in range(n):
                     r = self.active[s]
                     seq = self._seq[s]
@@ -388,32 +398,41 @@ class ServeEngine:
                             # replay only rebuilds KV, it emits nothing
                             kind = ("final" if nx + j == P - 1
                                     and not r.out else "chunk")
+                            n_final += kind == "final"
                             rows.append((s, int(seq[nx + j]),
                                          nx + j, kind))
                     else:
+                        n_decode += 1
                         rows.append((s, r.out[-1], int(self.pos[s]),
                                      "decode"))
                 for s in {row[0] for row in rows}:
                     self.kv.ensure_allocated(
                         s, max(p for sl, _, p, _ in rows if sl == s))
-                tables = jnp.asarray(
-                    self.kv.table_rows([row[0] for row in rows]))
-                toks = jnp.asarray([[t] for _, t, _, _ in rows], jnp.int32)
-                pos = jnp.asarray([p for _, _, p, _ in rows], jnp.int32)
-                eos = jnp.asarray(
-                    [(-1 if (k != "decode" or self.active[s].eos is None)
-                      else self.active[s].eos)
-                     for s, _, _, k in rows], jnp.int32)
-                # stochastic-draw keys: each row's (request seed, output
-                # index it produces).  Chunk rows' draws are discarded;
-                # keyed draws are stateless, so they disturb nothing.
-                seeds = jnp.asarray(
-                    [self._req_seed(self.active[s])
-                     for s, _, _, _ in rows], jnp.int32)
-                counters = jnp.asarray(
-                    [(len(self.active[s].out) if k == "decode" else 0)
-                     for s, _, _, k in rows], jnp.int32)
-            with obs.tracer.span("serve/forward", tokens=len(rows)):
+                with obs.tracer.span("serve/inputs", tokens=len(rows)):
+                    tables = jnp.asarray(
+                        self.kv.table_rows([row[0] for row in rows]))
+                    toks = jnp.asarray([[t] for _, t, _, _ in rows],
+                                       jnp.int32)
+                    pos = jnp.asarray([p for _, _, p, _ in rows], jnp.int32)
+                    eos = jnp.asarray(
+                        [(-1 if (k != "decode"
+                                 or self.active[s].eos is None)
+                          else self.active[s].eos)
+                         for s, _, _, k in rows], jnp.int32)
+                    # stochastic-draw keys: each row's (request seed,
+                    # output index it produces).  Chunk rows' draws are
+                    # discarded; keyed draws are stateless, so they
+                    # disturb nothing.
+                    seeds = jnp.asarray(
+                        [self._req_seed(self.active[s])
+                         for s, _, _, _ in rows], jnp.int32)
+                    counters = jnp.asarray(
+                        [(len(self.active[s].out) if k == "decode" else 0)
+                         for s, _, _, k in rows], jnp.int32)
+            with obs.tracer.span("serve/forward", tokens=len(rows),
+                                 decode_rows=n_decode,
+                                 prefill_rows=len(rows) - n_decode,
+                                 emits=n_decode + n_final):
                 tok, eos_hit, self.kv.pools, aux = self._pstep(
                     self.params, self.kv.pools, self._batch(toks), pos,
                     tables, eos, seeds, counters)
@@ -427,17 +446,20 @@ class ServeEngine:
             with obs.tracer.span("serve/postprocess"):
                 decode_row: Dict[int, int] = {}
                 chunks = np.zeros(n, np.int64)
+                emitted = []
                 for i, (s, _t, _p, kind) in enumerate(rows):
                     self._last_aux[self.active[s].rid] = aux
                     if kind == "decode":
-                        self._emit(self.active[s], int(tok_np[i]), t_now)
+                        self._emit(self.active[s], int(tok_np[i]), t_now,
+                                   emitted)
                         self.pos[s] += 1
                         decode_row[s] = i
                     else:
                         chunks[s] += 1
                         if kind == "final":   # prompt complete: 1st token
                             self._emit(self.active[s], int(tok_np[i]),
-                                       t_now)
+                                       t_now, emitted)
+                self._stream(emitted)
                 for s in np.nonzero(chunks)[0]:
                     self._prefill_next[s] += chunks[s]
                     self.pos[s] += chunks[s]
@@ -446,7 +468,6 @@ class ServeEngine:
                                             int(self._prefill_next[s]))
                 # retire top-down so compaction (move-last-into-freed)
                 # never moves a slot we still have to examine
-                n_decode = len(decode_row)
                 for s in range(n - 1, -1, -1):
                     if s not in decode_row:
                         continue
@@ -455,7 +476,7 @@ class ServeEngine:
                             or len(r.out) >= r.max_new \
                             or self.pos[s] >= self.capacity - 1:
                         self._retire(s, decode_batch=n_decode)
-        self._end_step(i_step, tokens=len(rows))
+            self._end_step(i_step, tokens=len(rows))
         return len(rows)
 
     def _end_step(self, i_step: int, *, tokens: int) -> None:
@@ -464,7 +485,7 @@ class ServeEngine:
         obs = self.obs
         obs.step_end(i_step)
         self._step_idx += 1
-        if obs.enabled:
+        if obs.metered:
             obs.metrics.inc("serve/steps")
             obs.metrics.inc("serve/step_tokens", tokens)
             if self.paged:
@@ -480,23 +501,25 @@ class ServeEngine:
             return 0
         obs, i_step = self.obs, self._step_idx
         obs.step_begin(i_step)
-        with obs.tracer.span("serve/step", step=i_step, active=n):
+        with obs.tracer.step("serve/step", i_step, active=n):
             with obs.tracer.span("serve/assemble"):
                 reqs = self.active[:n]
-                last = jnp.asarray([[r.out[-1]] for r in reqs],
-                                   jnp.int32)                    # (n, 1)
-                pos = jnp.asarray(self.pos[:n], jnp.int32)       # (n,)
-                eos = jnp.asarray([-1 if r.eos is None else r.eos
-                                   for r in reqs], jnp.int32)
                 fn = self._decode_steps.get(n)
                 if fn is None:
                     fn = self._decode_steps[n] = make_slot_decode_step(
                         self.cfg, self.rc, n, self.obs, self.sampling)
-                seeds = jnp.asarray([self._req_seed(r) for r in reqs],
-                                    jnp.int32)
-                counters = jnp.asarray([len(r.out) for r in reqs],
-                                       jnp.int32)
-            with obs.tracer.span("serve/forward", tokens=n):
+                with obs.tracer.span("serve/inputs", tokens=n):
+                    last = jnp.asarray([[r.out[-1]] for r in reqs],
+                                       jnp.int32)                # (n, 1)
+                    pos = jnp.asarray(self.pos[:n], jnp.int32)   # (n,)
+                    eos = jnp.asarray([-1 if r.eos is None else r.eos
+                                       for r in reqs], jnp.int32)
+                    seeds = jnp.asarray([self._req_seed(r) for r in reqs],
+                                        jnp.int32)
+                    counters = jnp.asarray([len(r.out) for r in reqs],
+                                           jnp.int32)
+            with obs.tracer.span("serve/forward", tokens=n, decode_rows=n,
+                                 prefill_rows=0, emits=n):
                 tok, eos_hit, self.cache, aux = fn(
                     self.params, self.cache, self._batch(last), pos, eos,
                     seeds, counters)
@@ -505,10 +528,12 @@ class ServeEngine:
                 tok_np, eos_np = jax.device_get((tok, eos_hit))
             t_now = self._clock()
             with obs.tracer.span("serve/postprocess"):
+                emitted = []
                 for s, r in enumerate(reqs):
-                    self._emit(r, int(tok_np[s]), t_now)
+                    self._emit(r, int(tok_np[s]), t_now, emitted)
                     self.pos[s] += 1
                     self._last_aux[r.rid] = aux
+                self._stream(emitted)
                 # retire top-down so the swap-with-last compaction never
                 # moves a slot we still have to examine
                 for s in range(n - 1, -1, -1):
@@ -516,7 +541,7 @@ class ServeEngine:
                     if bool(eos_np[s]) or len(r.out) >= r.max_new \
                             or self.pos[s] >= self.capacity - 1:
                         self._retire(s, decode_batch=n)
-        self._end_step(i_step, tokens=n)
+            self._end_step(i_step, tokens=n)
         return n
 
     # ------------------------------------------------------------------
@@ -533,47 +558,50 @@ class ServeEngine:
         (contiguous: always 0.0, no prefix index) — and the ``lat/*``
         latency family (obs/latency.py)."""
         req = self.active[s]
-        req.stats = {k: float(v)
-                     for k, v in self._last_aux.pop(req.rid).items()}
-        req.stats["serve/decode_batch"] = float(decode_batch)
-        if self.paged:
-            req.stats["serve/prefix_hit_tokens"] = float(self._prefix_hit[s])
-            req.stats["serve/prefill_forwards"] = \
-                float(self._prefill_forwards[s])
-            self.kv.release_slot(s)
-        else:
-            req.stats["serve/prefix_hit_tokens"] = 0.0
-            req.stats["serve/prefill_forwards"] = 1.0
-        self._compact(s)
-        tl = self._timing.pop(req.rid, None)
-        if tl is not None:
-            req.stats.update(tl.finalize(end=self._clock()))
-        req.done = True
-        obs = self.obs
-        obs.tracer.instant("serve/retire", rid=req.rid)
-        if obs.enabled:
-            m = obs.metrics
-            m.inc("serve/completed")
-            for key in ("ttft_s", "tpot_s", "queue_wait_s", "e2e_s"):
-                if f"lat/{key}" in req.stats:
-                    m.observe(f"serve/{key}", req.stats[f"lat/{key}"])
-            # SLO outcome at retirement: deadline misses by family
-            if req.slo_ttft is not None \
-                    and req.stats.get("lat/ttft_s", 0.0) > req.slo_ttft:
-                m.inc("serve/slo_ttft_miss")
-            if req.slo_tpot is not None \
-                    and req.stats.get("lat/tpot_s", 0.0) > req.slo_tpot:
-                m.inc("serve/slo_tpot_miss")
-            # absorb the retirement-time plan stats (summed over the MoE
-            # layers of the request's final step) as histogram samples
-            m.observe_many("", {k: v for k, v in req.stats.items()
-                                if k.startswith("sched/")})
-            # under EP the skew table must stay honest: dropped-token
-            # totals from the sharded/replicated dispatch accumulate into
-            # a dedicated counter
-            if self.rc.ep and "sched/dropped_rows" in req.stats:
-                m.inc("serve/ep_dropped_tokens",
-                      int(req.stats["sched/dropped_rows"]))
+        with self.obs.tracer.span("serve/retire", rid=req.rid):
+            req.stats = {k: float(v)
+                         for k, v in self._last_aux.pop(req.rid).items()}
+            req.stats["serve/decode_batch"] = float(decode_batch)
+            if self.paged:
+                req.stats["serve/prefix_hit_tokens"] = \
+                    float(self._prefix_hit[s])
+                req.stats["serve/prefill_forwards"] = \
+                    float(self._prefill_forwards[s])
+                self.kv.release_slot(s)
+            else:
+                req.stats["serve/prefix_hit_tokens"] = 0.0
+                req.stats["serve/prefill_forwards"] = 1.0
+            self._compact(s)
+            tl = self._timing.pop(req.rid, None)
+            if tl is not None:
+                req.stats.update(tl.finalize(end=self._clock()))
+            req.done = True
+            obs = self.obs
+            if obs.metered:
+                m = obs.metrics
+                m.inc("serve/completed")
+                for key in ("ttft_s", "tpot_s", "queue_wait_s", "e2e_s"):
+                    if f"lat/{key}" in req.stats:
+                        m.observe(f"serve/{key}", req.stats[f"lat/{key}"])
+                # SLO outcome at retirement: deadline misses by family
+                lat = req.stats
+                if req.slo_ttft is not None \
+                        and lat.get("lat/ttft_s", 0.0) > req.slo_ttft:
+                    m.inc("serve/slo_ttft_miss")
+                if req.slo_tpot is not None \
+                        and lat.get("lat/tpot_s", 0.0) > req.slo_tpot:
+                    m.inc("serve/slo_tpot_miss")
+                # absorb the retirement-time plan stats (summed over the
+                # MoE layers of the request's final step) as histogram
+                # samples
+                m.observe_many("", {k: v for k, v in req.stats.items()
+                                    if k.startswith("sched/")})
+                # under EP the skew table must stay honest: dropped-
+                # token totals from the sharded/replicated dispatch
+                # accumulate into a dedicated counter
+                if self.rc.ep and "sched/dropped_rows" in req.stats:
+                    m.inc("serve/ep_dropped_tokens",
+                          int(req.stats["sched/dropped_rows"]))
 
     def _compact(self, s: int) -> None:
         """Vacate slot ``s`` keeping the active prefix contiguous (paged:
@@ -660,12 +688,14 @@ class ServeEngine:
         exposing a ``.preempt(engine, pending)`` hook, e.g. ``slo``), then
         fill free slots from ``pending`` (mutated in place; victims of
         preemption rejoin it, resumable)."""
-        pre = getattr(self._admission, "preempt", None)
-        if pre is not None and pending:
-            for s in sorted(pre(self, pending), reverse=True):
-                pending.append(self.preempt(s))
-        while pending and self.n_active < self.slots:
-            self.admit(pending.pop(self._admission(pending, engine=self)))
+        with self.obs.tracer.span("serve/schedule", pending=len(pending)):
+            pre = getattr(self._admission, "preempt", None)
+            if pre is not None and pending:
+                for s in sorted(pre(self, pending), reverse=True):
+                    pending.append(self.preempt(s))
+            while pending and self.n_active < self.slots:
+                self.admit(pending.pop(self._admission(pending,
+                                                       engine=self)))
 
     def run(self, requests: List[Request], max_steps: int = 512):
         """Drive admission + decode until done (or the step budget runs

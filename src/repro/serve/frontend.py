@@ -12,9 +12,10 @@ they are produced, not at retirement.  This module is that front end
   inside the next ``poll()``, under whatever admission policy the engine
   was built with (the ``slo`` policy preempts through the same pass).
 * **poll()** drives one (or more) scheduling pass + engine step and
-  returns the requests that finished during it.  Token callbacks fire
-  from the engine's ``on_token`` hook — the moment the step's ONE host
-  sync retires each token into ``Request.out``.  Streaming therefore
+  returns the requests that finished during it; each pass is a
+  ``serve/poll`` span of the engine's tracer.  Token callbacks fire
+  from the engine's ``on_token`` hook as soon as the step's ONE host
+  sync has retired its tokens into ``Request.out``.  Streaming therefore
   adds ZERO device syncs, and the streamed sequence is bitwise-identical
   to what a closed-batch ``run()`` would produce (asserted in
   tests/test_serve.py across dense/MoE x paged/contiguous).
@@ -93,12 +94,15 @@ class ServingFrontend:
         COMPLETED during this poll (retired handles leave the in-flight
         table, so each completion is reported exactly once)."""
         done: List[Request] = []
+        tracer = self.engine.obs.tracer
         for _ in range(max(1, steps)):
-            self.engine.schedule(self.pending)
-            n = self.engine.step()
-            for rid in [rid for rid, r in self._inflight.items() if r.done]:
-                done.append(self._inflight.pop(rid))
-                self._callbacks.pop(rid, None)
+            with tracer.span("serve/poll"):
+                self.engine.schedule(self.pending)
+                n = self.engine.step()
+                for rid in [rid for rid, r in self._inflight.items()
+                            if r.done]:
+                    done.append(self._inflight.pop(rid))
+                    self._callbacks.pop(rid, None)
             if n == 0 and not self.pending:
                 break                  # idle: nothing left to schedule
         return done

@@ -263,17 +263,18 @@ class PagedKVCache:
         KV depends only on the token prefix (greedy, fixed params)."""
         if not self.prefix_cache or slot not in self._chain:
             return
-        bs = self.block_size
-        i, digest = self._chain[slot]
-        while (i + 1) * bs <= n_processed:
-            digest = _chain_digest(digest, prompt[i * bs:(i + 1) * bs])
-            b = int(self.tables[slot, i])
-            if digest not in self._hash_to_block:
-                self._hash_to_block[digest] = b
-                self._block_hash[b] = digest
-                self._index_mutated()
-            i += 1
-        self._chain[slot] = (i, digest)
+        with self._tracer.span("serve/prefix_register", slot=slot):
+            bs = self.block_size
+            i, digest = self._chain[slot]
+            while (i + 1) * bs <= n_processed:
+                digest = _chain_digest(digest, prompt[i * bs:(i + 1) * bs])
+                b = int(self.tables[slot, i])
+                if digest not in self._hash_to_block:
+                    self._hash_to_block[digest] = b
+                    self._block_hash[b] = digest
+                    self._index_mutated()
+                i += 1
+            self._chain[slot] = (i, digest)
 
     def truncate_slot(self, slot: int, n_tokens: int) -> int:
         """Roll ``slot``'s logical sequence back to its first ``n_tokens``

@@ -224,7 +224,7 @@ def replay(engine, trace: List[TraceEvent], *, clock: VirtualClock,
     if engine.paged:
         rec["kv_stats"] = engine.kv.stats()
     obs = engine.obs
-    if obs.enabled:
+    if obs.metered:
         # per-phase counters: scheduling/preemption/streaming activity
         snap = obs.metrics.snapshot()
         rec["obs_counters"] = {c["name"]: c["value"]

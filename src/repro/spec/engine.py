@@ -245,8 +245,7 @@ class SpecEngine(ServeEngine):
         obs.step_begin(i_step)
         reqs = self.active[:n]
         pos0 = self.pos[:n].astype(np.int64).copy()
-        with obs.tracer.span("serve/step", step=i_step, active=n,
-                             spec_k=k):
+        with obs.tracer.step("serve/step", i_step, active=n, spec_k=k):
             seeds = jnp.asarray([self._req_seed(r) for r in reqs],
                                 jnp.int32)
             counters = jnp.asarray([len(r.out) for r in reqs], jnp.int32)
@@ -291,6 +290,7 @@ class SpecEngine(ServeEngine):
             t_now = self._clock()
             with obs.tracer.span("serve/postprocess"):
                 acc_round = 0
+                emitted = []
                 for s in range(n):
                     r = reqs[s]
                     self._last_aux[r.rid] = aux
@@ -299,7 +299,7 @@ class SpecEngine(ServeEngine):
                         if len(r.out) >= r.max_new:
                             break
                         tok = int(em_np[s, j])
-                        self._emit(r, tok, t_now)
+                        self._emit(r, tok, t_now, emitted)
                         m += 1
                         if r.eos is not None and tok == r.eos:
                             break
@@ -313,9 +313,10 @@ class SpecEngine(ServeEngine):
                     self._dnext[s] = dn
                     self.n_drafted += k
                     acc_round += max(0, min(m, ne - 1))
+                self._stream(emitted)
                 self.n_accepted += acc_round
                 self.n_spec_rounds += 1
-                if obs.enabled:
+                if obs.metered:
                     obs.metrics.inc("spec/rounds")
                     obs.metrics.inc("spec/drafted", n * k)
                     obs.metrics.inc("spec/accepted", acc_round)
@@ -329,5 +330,5 @@ class SpecEngine(ServeEngine):
                             or len(r.out) >= r.max_new \
                             or self.pos[s] >= self.capacity - 1:
                         self._retire(s, decode_batch=n)
-        self._end_step(i_step, tokens=n * (k + 1))
+            self._end_step(i_step, tokens=n * (k + 1))
         return n * (k + 1)

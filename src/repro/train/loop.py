@@ -80,7 +80,7 @@ def train(cfg: ModelConfig, rc: RunConfig, opt: OptConfig, *,
             if step % log_every == 0 or step == steps - 1:
                 m = {k: float(np.asarray(v)) for k, v in metrics.items()}
                 history.append({"step": step, **m})
-                if obs.enabled:
+                if obs.metered:
                     obs.metrics.inc("train/steps_logged")
                     obs.metrics.observe_many("train/", m)
                 log(f"[train] step {step:5d} loss {m.get('loss', 0):.4f} "

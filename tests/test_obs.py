@@ -9,7 +9,6 @@ import jax
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.execution.base import set_plan_hook
 from repro.models import RunConfig, init_params
 from repro.obs import (LAT_KEYS, NOOP, MetricsRegistry, NullMetrics,
                        Observability, RequestTimeline, SpanTracer, aggregate,
@@ -89,7 +88,7 @@ def test_null_metrics_absorbs_everything():
 def test_sink_registry():
     assert {"null", "memory"} <= set(available_sinks())
     assert get_sink("null") is NOOP
-    assert get_sink("memory").enabled
+    assert get_sink("memory").metered
     with pytest.raises(ValueError, match="unknown observability sink"):
         get_sink("nope")
 
@@ -192,10 +191,7 @@ def _run(cfg, *, obs=None, kv_block_size=None):
     eng = ServeEngine(cfg, params, slots=2, capacity=32, rc=RC,
                       kv_block_size=kv_block_size, obs=obs)
     reqs = _mk_reqs(cfg, 4)
-    try:
-        done = eng.run(reqs, max_steps=256)
-    finally:
-        set_plan_hook(None)         # engine installs a process-global hook
+    done = eng.run(reqs, max_steps=256)
     assert len(done) == len(reqs)
     return reqs, eng
 
@@ -269,28 +265,14 @@ def test_engine_metrics_and_trace_absorbed():
 
 
 def test_recompile_and_plan_trace_events():
-    """Trace-time hooks fire once per compiled shape: the MoE paged run
-    compiles >= 1 step shape, each traced plan_dispatch counts under
-    moe/plans_traced, and both leave instants in the trace."""
+    """The trace-time hook fires once per compiled shape: the MoE paged
+    run compiles >= 1 step shape, counted under serve/recompiles and left
+    as an instant in the trace."""
     obs = Observability.memory()
     _run(MOE(), obs=obs)
     m = obs.metrics
     assert m.counter_value("serve/recompiles", kind="paged_step") >= 1
-    assert m.counter_value("moe/plans_traced", executor="xla",
-                           policy="fixed") >= 1
-    names = {e["name"] for e in obs.tracer.events}
-    assert {"recompile", "plan_trace"} <= names
-
-
-def test_plan_hook_restores_previous():
-    calls = []
-    prev = set_plan_hook(lambda **kw: calls.append(kw))
-    try:
-        assert prev is None
-        restored = set_plan_hook(None)
-        assert callable(restored)
-    finally:
-        set_plan_hook(None)
+    assert "recompile" in {e["name"] for e in obs.tracer.events}
 
 
 def test_quantized_expert_bytes_gauge():
@@ -299,10 +281,7 @@ def test_quantized_expert_bytes_gauge():
     obs = Observability.memory()
     rc = RunConfig(q_chunk=16, kv_chunk=16, quant="int8_expert")
     eng = ServeEngine(cfg, params, slots=2, capacity=32, rc=rc, obs=obs)
-    try:
-        eng.run(_mk_reqs(cfg, 2))
-    finally:
-        set_plan_hook(None)
+    eng.run(_mk_reqs(cfg, 2))
     assert obs.metrics.gauge_value("serve/quant_expert_bytes",
                                    scheme="int8_expert") > 0
 
@@ -313,14 +292,146 @@ def test_dropped_requests_counted():
     obs = Observability.memory()
     eng = ServeEngine(cfg, params, slots=1, capacity=32, rc=RC, obs=obs)
     reqs = _mk_reqs(cfg, 2, max_new=8)
-    try:
-        eng.run(reqs, max_steps=3)
-    finally:
-        set_plan_hook(None)
+    eng.run(reqs, max_steps=3)
     assert eng.dropped
     assert obs.metrics.counter_value("serve/dropped") == len(eng.dropped)
     assert "serve/step_budget_exhausted" in \
         {e["name"] for e in obs.tracer.events}
+
+
+# ---------------------------------------------------------------------------
+# Engine spans: nesting, counts, the profiler's trace
+# ---------------------------------------------------------------------------
+def _serve_frontend(obs, n_reqs=3):
+    """A tiny paged MoE engine behind the front end, run to the end."""
+    from repro.serve.frontend import ServingFrontend
+    cfg = MOE()
+    params = init_params(cfg, jax.random.key(0))
+    eng = ServeEngine(cfg, params, slots=2, capacity=32, rc=RC,
+                      prefill_chunk=2, obs=obs)
+    fe = ServingFrontend(eng)
+    streamed = []
+    reqs = [fe.submit(r.prompt, max_new=r.max_new, rid=r.rid,
+                      on_token=lambda req, tok: streamed.append(tok))
+            for r in _mk_reqs(cfg, n_reqs)]
+    fe.drain(max_steps=256)
+    assert all(r.done for r in reqs)
+    return reqs, streamed
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[0] + inner[1] <= outer[0] + outer[1]
+
+
+def test_engine_spans_nest_and_carry_host_counts():
+    """Every piece of per-step host work is a span inside the one it is
+    called from, and the forward span's counts add up: its rows split
+    into decode and prefill rows, and over the run its emits are the
+    tokens served (each streamed once)."""
+    obs = Observability(tracer=SpanTracer())
+    reqs, streamed = _serve_frontend(obs)
+    spans = [(e["ts"], e["dur"], e["name"], e.get("args", {}))
+             for e in obs.tracer.events if e["ph"] == "X"]
+    by = {}
+    for sp in spans:
+        by.setdefault(sp[2], []).append(sp)
+    for inner, outer in [("serve/schedule", "serve/poll"),
+                         ("serve/admit", "serve/schedule"),
+                         ("serve/prefix_probe", "serve/admit"),
+                         ("serve/step", "serve/poll"),
+                         ("serve/assemble", "serve/step"),
+                         ("serve/inputs", "serve/assemble"),
+                         ("serve/forward", "serve/step"),
+                         ("serve/host_sync", "serve/step"),
+                         ("serve/postprocess", "serve/step"),
+                         ("serve/stream", "serve/postprocess"),
+                         ("serve/prefix_register", "serve/postprocess"),
+                         ("serve/retire", "serve/postprocess")]:
+        assert by.get(inner), inner
+        for sp in by[inner]:
+            assert any(_inside(sp, o) for o in by[outer]), (inner, outer)
+    fwd = [a for _, _, _, a in by["serve/forward"]]
+    assert all(a["tokens"] == a["decode_rows"] + a["prefill_rows"]
+               for a in fwd)
+    served = sum(len(r.out) for r in reqs)
+    assert sum(a["emits"] for a in fwd) == served == len(streamed)
+    assert sum(a["args"]["tokens"] for a in obs.tracer.events
+               if a["name"] == "serve/stream") == served
+    assert [a["step"] for _, _, _, a in by["serve/step"]] == \
+        list(range(len(by["serve/step"])))
+    assert not any(e["name"] == "serve/retire" and e["ph"] == "i"
+                   for e in obs.tracer.events)
+
+
+def _profiled(tmp_path, obs):
+    """Run the tiny engine under ``jax.profiler``; the host's
+    ``serve/`` events of the profile, as (name, start_ns, dur_ns,
+    stats)."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve_frontend(obs)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for ln in plane.lines for e in ln.events
+            if e.name.startswith("serve/")]
+
+
+def test_spans_land_in_the_profilers_trace(tmp_path):
+    """A SpanTracer's spans are profiler annotations: each in-memory
+    span has one host event of its name in the profile, nested as the
+    calls nest, with the span's host counts as stats."""
+    obs = Observability(tracer=SpanTracer())
+    host = _profiled(tmp_path, obs)
+    mem = [e for e in obs.tracer.events if e["ph"] == "X"]
+    count = lambda names: {n: names.count(n) for n in set(names)}
+    assert count([h[0] for h in host]) == count([e["name"] for e in mem])
+    by = {}
+    for h in host:
+        by.setdefault(h[0], []).append((h[1], h[2]))
+    for inner, outer in [("serve/inputs", "serve/assemble"),
+                         ("serve/assemble", "serve/step"),
+                         ("serve/step", "serve/poll")]:
+        for sp in by[inner]:
+            assert any(_inside(sp, o) for o in by[outer]), (inner, outer)
+    fwd_mem = [e["args"]["tokens"] for e in mem
+               if e["name"] == "serve/forward"]
+    fwd_host = [st for n, _, _, st in sorted(host, key=lambda h: h[1])
+                if n == "serve/forward"]
+    assert [int(st["tokens"]) for st in fwd_host] == fwd_mem
+    steps = [st for n, _, _, st in sorted(host, key=lambda h: h[1])
+             if n == "serve/step"]
+    assert [int(st["step_num"]) for st in steps] == list(range(len(steps)))
+
+
+def test_null_tracer_emits_nothing(tmp_path):
+    """The default bundle puts nothing into a profile and hands back one
+    shared context for every span."""
+    assert NOOP.tracer.span("serve/x", a=1) is NOOP.tracer.step("s", 0)
+    assert _profiled(tmp_path, NOOP) == []
+    assert NOOP.tracer.events == []
+
+
+def test_metric_feeding_work_needs_a_metrics_sink(monkeypatch):
+    """A bundle that only traces reads no pool statistics per step; one
+    with a metrics registry does."""
+    from repro.serve.kv_cache import PagedKVCache
+    calls = []
+    stats = PagedKVCache.stats
+    monkeypatch.setattr(PagedKVCache, "stats",
+                        lambda self: calls.append(1) or stats(self))
+    traced = Observability(tracer=SpanTracer())
+    assert not traced.metered
+    _run(MOE(), obs=traced)
+    assert calls == []
+    _run(MOE(), obs=Observability.memory())
+    assert calls
 
 
 # ---------------------------------------------------------------------------
