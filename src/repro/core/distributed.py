@@ -166,7 +166,8 @@ def _ep_stats(axes, *, kept, dropped, counts_local, sched):
         pad_waste=safe(padded, useful),
         drop_fraction=safe(dropped, useful + dropped),
         top1_share=safe(jnp.max(counts_g), total),
-        n_blocks_active=n_active, occupancy=safe(useful, padded))
+        n_blocks_active=n_active, occupancy=safe(useful, padded),
+        grid_rows=jax.lax.psum(jnp.int32(sched.capacity), axes))
     return {f"sched/{k}": v for k, v in st._asdict().items()}
 
 
@@ -377,7 +378,7 @@ def _merge_chunk_aux(auxes):
     n = len(auxes)
     out = {}
     add = ("sched/useful_rows", "sched/dropped_rows", "sched/padded_rows",
-           "sched/n_blocks_active")
+           "sched/n_blocks_active", "sched/grid_rows")
     for k in auxes[0]:
         if k in add:
             out[k] = sum(a[k] for a in auxes)

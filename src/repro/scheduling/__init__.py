@@ -13,6 +13,6 @@ from repro.scheduling.base import (DEFAULT_POLICY_SWEEP,  # noqa: F401
 from repro.scheduling.capacity import (build_capacity_schedule,  # noqa: F401
                                        capacity_slots, expert_capacity)
 from repro.scheduling.dynamic import (build_dynamic_schedule,  # noqa: F401
-                                      sub_block)
+                                      dynamic_capacity, sub_block)
 from repro.scheduling.fixed import (build_fixed_schedule,  # noqa: F401
                                     schedule_capacity)

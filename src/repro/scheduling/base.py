@@ -72,6 +72,7 @@ class ScheduleStats(NamedTuple):
     top1_share: jnp.ndarray      # heaviest expert's share of raw routing
     n_blocks_active: jnp.ndarray
     occupancy: jnp.ndarray       # useful_rows / padded_rows
+    grid_rows: jnp.ndarray       # static capacity: rows the kernels' grid walks
 
 
 def schedule_stats(sched: BlockSchedule) -> ScheduleStats:
@@ -93,6 +94,7 @@ def schedule_stats(sched: BlockSchedule) -> ScheduleStats:
         top1_share=safe(jnp.max(sched.counts), total),
         n_blocks_active=n_active,
         occupancy=safe(useful, padded),
+        grid_rows=jnp.int32(sched.capacity),
     )
 
 

@@ -29,16 +29,33 @@ Zipfian skew, because every light expert pads its partial tile up to a full
    i.e. light experts share padding.  Heavy experts own proportionally more
    of the (now finer) block list: blocks-per-expert = padded_c / q.
 
+3. **A static envelope sized to the padding it can reach.**  The grid is
+   static, so the capacity is a worst case over every routing of T*k
+   assignments to E experts (``dynamic_capacity``).  A light expert
+   (0 < c < block_m) wastes at most q-1 rows; a heavy one at most
+   block_m-1.  At most min(E, T*k) experts get a route and at most
+   min(E, floor(T*k/block_m)) of them are heavy, so the padded total
+   never exceeds
+
+       B = T*k + min(E, T*k)*(q-1) + min(E, floor(T*k/block_m))*(block_m-q)
+
+   Capacity is min(fixed's envelope, round_up(B, 8*q)): at decode-sized
+   T it is a few dozen sub-blocks where fixed's E*block_m term alone is
+   E*block_m/q of them, and at large T it never exceeds fixed's.  The
+   8*q rounding keeps the permute/unpermute row blocks at 64-128 rows.
+   The active blocks are packed from row 0, so shrinking the envelope
+   only drops trailing inactive blocks.
+
 Everything is jnp on-device (argsort / cumsum / searchsorted) — no host
 round-trip, so the TPU no-host-sync property of the fixed policy is
-preserved; the capacity envelope reuses fixed's static worst case, so jit
-shapes are load-independent.
+preserved; the envelope depends only on static shapes, so jit shapes are
+load-independent.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.scheduling.base import BlockSchedule, register_policy
+from repro.scheduling.base import BlockSchedule, register_policy, round_up
 from repro.scheduling.fixed import schedule_capacity
 
 
@@ -55,6 +72,18 @@ def sub_block(block_m: int, block_m_min: int = 8) -> int:
     return block_m
 
 
+def dynamic_capacity(n_tokens: int, top_k: int, n_experts: int,
+                     block_m: int, q: int) -> int:
+    """Static row envelope of a ``dynamic`` schedule: the padded total B
+    it can reach on any routing (module docstring, 3.), rounded up to
+    8*q rows, and never above the ``fixed`` policy's envelope."""
+    n = n_tokens * top_k
+    reach = (n + min(n_experts, n) * (q - 1)
+             + min(n_experts, n // block_m) * (block_m - q))
+    return min(schedule_capacity(n_tokens, top_k, n_experts, block_m),
+               round_up(reach, 8 * q))
+
+
 @register_policy("dynamic", config_fields=("block_m_min",))
 def build_dynamic_schedule(indices: jnp.ndarray, n_experts: int,
                            block_m: int, *,
@@ -62,7 +91,7 @@ def build_dynamic_schedule(indices: jnp.ndarray, n_experts: int,
     T, k = indices.shape
     E, M = n_experts, block_m
     q = sub_block(M, block_m_min)
-    capacity = schedule_capacity(T, k, E, M)   # fixed policy's static envelope
+    capacity = dynamic_capacity(T, k, E, M, q)
     num_blocks = capacity // q
 
     flat = indices.reshape(-1).astype(jnp.int32)

@@ -247,3 +247,251 @@ def test_dynamic_sub_block_divides_block_m():
     assert sub_block(12) == 12              # no aligned divisor -> fixed
     assert sub_block(4) == 4
     assert sub_block(32, block_m_min=4) == 8    # floor clamped to sublane
+
+
+# ---------------------------------------------------------------------------
+# The dynamic policy's static envelope: sized to the padding it can reach.
+# ---------------------------------------------------------------------------
+from repro.core.distributed import _merge_chunk_aux  # noqa: E402
+from repro.scheduling import dynamic_capacity, schedule_capacity  # noqa: E402
+from repro.scheduling import dynamic as dynamic_mod  # noqa: E402
+
+ENVELOPE_TS = tuple(range(1, 41)) + (64, 256, 4096)
+ENVELOPE_KS = (1, 2, 6)
+EXACT_MAX_ROWS = 384          # exact worst case over every routing up to here
+
+
+def padded_rows_of(counts, M, q):
+    """The dynamic policy's padding rule, per expert count (numpy)."""
+    c = np.asarray(counts, np.int64)
+    return np.where(c >= M, -(-c // M) * M, -(-c // q) * q)
+
+
+def worst_padded_total(n_max, E, M, q):
+    """worst[n]: the largest padded total over EVERY split of n
+    assignments among E experts (max-plus power of the per-expert
+    padding, by doubling)."""
+    pad = padded_rows_of(np.arange(n_max + 1), M, q).astype(np.float64)
+    s = np.arange(n_max + 1)
+    shift = s[:, None] - s[None, :]
+
+    def conv(a, b):
+        vals = np.where(shift >= 0, a[np.clip(shift, 0, None)] + b[None, :],
+                        -np.inf)
+        return vals.max(axis=1)
+
+    out = np.where(s == 0, 0.0, -np.inf)       # zero experts: only n = 0
+    base, e = pad, E
+    while e:
+        if e & 1:
+            out = conv(out, base)
+        base, e = conv(base, base), e >> 1
+    return out.astype(np.int64)
+
+
+def adversarial_counts(n, E, M, q, pattern, rng):
+    """Per-expert counts summing to n that push the padding up."""
+    counts = np.zeros(E, np.int64)
+    if pattern == "one_expert":
+        counts[0] = n
+    elif pattern == "random":
+        counts = np.bincount(rng.integers(0, E, n), minlength=E)
+    elif pattern == "light_one_mod_q":
+        # every touched expert at count = 1 (mod q), light while it can be
+        touched = min(E, n)
+        counts[:touched] = 1
+        left = n - touched
+        room = max(M - 2, 0) // q          # q-chunks that keep c < M
+        for e in range(touched):
+            add = min(left // q, room) * q
+            counts[e] += add
+            left -= add
+        counts[0] += left
+    elif pattern == "heavy_j_m_plus_one":
+        # as many experts as fit at j*M + 1 rows, the rest at 1 (mod q)
+        heavy = min(E, n // (M + 1))
+        counts[:heavy] = M + 1
+        left = n - heavy * (M + 1)
+        rest = min(E - heavy, left)
+        counts[heavy:heavy + rest] = 1
+        counts[0] += left - rest
+    else:
+        raise ValueError(pattern)
+    assert counts.sum() == n
+    return counts
+
+
+def routing_of(counts, T, k, rng):
+    flat = np.repeat(np.arange(len(counts)), counts)
+    return rng.permutation(flat).reshape(T, k).astype(np.int32)
+
+
+ENVELOPE_PATTERNS = ("light_one_mod_q", "heavy_j_m_plus_one", "one_expert",
+                     "random")
+
+
+@pytest.mark.parametrize("M,block_m_min", [(8, 8), (16, 8), (16, 16),
+                                           (128, 8), (128, 128)])
+@pytest.mark.parametrize("E", [8, 64, 65])
+def test_dynamic_envelope_bounds_every_routing(E, M, block_m_min):
+    """capacity is a multiple of q, never above fixed's envelope, equal to
+    it once B(T) rounded up reaches it, and at least the padded total of
+    EVERY routing (exactly, by the worst split, up to EXACT_MAX_ROWS
+    assignments; on adversarial routings beyond)."""
+    q = sub_block(M, block_m_min)
+    assert (q < M) == (block_m_min < M)
+    worst = worst_padded_total(EXACT_MAX_ROWS, E, M, q)
+    rng = np.random.default_rng(E * 1000 + M + block_m_min)
+    reached = 0
+    for T in ENVELOPE_TS:
+        for k in ENVELOPE_KS:
+            n = T * k
+            cap = dynamic_capacity(T, k, E, M, q)
+            fixed = schedule_capacity(T, k, E, M)
+            reach = (n + min(E, n) * (q - 1)
+                     + min(E, n // M) * (M - q))
+            assert cap % q == 0, (T, k)
+            assert cap <= fixed, (T, k)
+            rounded = -(-reach // (8 * q)) * 8 * q
+            if rounded >= fixed:
+                reached += 1
+                assert cap == fixed, (T, k)
+            else:
+                assert cap == rounded, (T, k)
+            if n <= EXACT_MAX_ROWS:
+                assert worst[n] <= reach <= cap, (T, k, worst[n], cap)
+            for pattern in ENVELOPE_PATTERNS:
+                counts = adversarial_counts(n, E, M, q, pattern, rng)
+                assert padded_rows_of(counts, M, q).sum() <= cap, \
+                    (T, k, pattern)
+    if (E, M) == (8, 8):       # the envelope reaches fixed's in this sweep
+        assert reached > 0
+
+
+@pytest.mark.parametrize("T,k,E,M,block_m_min", [
+    (1, 6, 64, 128, 8),        # T*k < E: few experts touched
+    (14, 6, 64, 128, 8),       # T*k > E: the chat cell's decode step
+    (40, 2, 65, 16, 8),
+    (40, 6, 8, 16, 16),        # q == M
+    (64, 1, 65, 8, 8),         # q == M == 8
+    (256, 6, 64, 128, 8),
+    (4096, 6, 8, 8, 8),        # B(T) reaches the fixed envelope
+])
+def test_dynamic_schedule_fits_adversarial_routings(T, k, E, M, block_m_min):
+    """Built schedules keep every route inside the tight envelope."""
+    rng = np.random.default_rng(T + E + M)
+    q = sub_block(M, block_m_min)
+    for pattern in ENVELOPE_PATTERNS:
+        idx = routing_of(adversarial_counts(T * k, E, M, q, pattern, rng),
+                         T, k, rng)
+        sched = build_schedule(jnp.asarray(idx), E, M, policy="dynamic",
+                               block_m_min=block_m_min)
+        st = schedule_stats(sched)
+        assert sched.block_m == q
+        assert sched.capacity == dynamic_capacity(T, k, E, M, q)
+        assert sched.capacity % q == 0
+        assert sched.capacity <= schedule_capacity(T, k, E, M)
+        assert int((np.asarray(sched.src_tok) >= 0).sum()) == T * k, pattern
+        assert int(st.dropped_rows) == 0
+        assert int(st.padded_rows) <= sched.capacity, pattern
+        assert int(st.grid_rows) == sched.capacity
+        if T * k <= 256:
+            check_schedule_invariants(idx, E, M, "dynamic",
+                                      {"block_m_min": block_m_min})
+
+
+def _extended(sched, capacity):
+    """The same schedule carried to a larger envelope: padding rows and
+    inactive blocks appended, the tail's owner clamped."""
+    q = sched.block_m
+    grow = capacity - sched.capacity
+    be = np.asarray(sched.block_expert)
+    return sched._replace(
+        src_tok=jnp.concatenate([sched.src_tok,
+                                 jnp.full((grow,), -1, jnp.int32)]),
+        block_active=jnp.concatenate([sched.block_active,
+                                      jnp.zeros((grow // q,), jnp.int32)]),
+        block_expert=jnp.concatenate([sched.block_expert,
+                                      jnp.full((grow // q,), be[-1],
+                                               jnp.int32)]),
+        capacity=capacity)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("T", [1, 14, 32])
+def test_tight_dynamic_envelope_is_bitwise_equal_to_the_fixed_one(
+        T, impl, monkeypatch):
+    """At Moonlight-like routing (64 experts, top-6, block_m 128, sigmoid)
+    the MoE layer gives bitwise the output it gave on the fixed envelope,
+    and the schedule's active prefix is the same."""
+    E, k, M, d, f = 64, 6, 128, 32, 24
+    cfg = MoEDispatchConfig(n_experts=E, top_k=k, block_m=M, executor=impl,
+                            schedule_policy="dynamic", gating="sigmoid",
+                            norm_topk=True, routed_scale=2.446)
+    ks = jax.random.split(jax.random.key(T), 5)
+    x = jax.random.normal(ks[0], (T, d))
+    wr = jax.random.normal(ks[1], (d, E)) * 0.3
+    wg = jax.random.normal(ks[2], (E, d, f)) * 0.3
+    wu = jax.random.normal(ks[3], (E, d, f)) * 0.3
+    wd = jax.random.normal(ks[4], (E, f, d)) * 0.3
+    _, indices, _ = route(x, wr, cfg)
+
+    tight = build_schedule(indices, E, M, policy="dynamic")
+    y_tight, _ = moe_ffn(x, wr, wg, wu, wd, cfg)
+    with monkeypatch.context() as mp:
+        # the policy as it was on fixed's envelope
+        mp.setattr(dynamic_mod, "dynamic_capacity",
+                   lambda T, k, E, M, q: schedule_capacity(T, k, E, M))
+        wide = build_schedule(indices, E, M, policy="dynamic")
+        y_wide, _ = moe_ffn(x, wr, wg, wu, wd, cfg)
+    assert tight.capacity < wide.capacity == schedule_capacity(T, k, E, M)
+
+    nb = tight.capacity // tight.block_m
+    np.testing.assert_array_equal(np.asarray(tight.pos),
+                                  np.asarray(wide.pos))
+    np.testing.assert_array_equal(np.asarray(tight.src_tok),
+                                  np.asarray(wide.src_tok)[:tight.capacity])
+    np.testing.assert_array_equal(np.asarray(tight.block_active),
+                                  np.asarray(wide.block_active)[:nb])
+    np.testing.assert_array_equal(np.asarray(tight.block_expert),
+                                  np.asarray(wide.block_expert)[:nb])
+    ext = _extended(tight, wide.capacity)
+    for name in ("src_tok", "block_active", "block_expert"):
+        np.testing.assert_array_equal(np.asarray(getattr(ext, name)),
+                                      np.asarray(getattr(wide, name)), name)
+    np.testing.assert_array_equal(np.asarray(y_tight), np.asarray(y_wide))
+
+
+@pytest.mark.parametrize("policy,kw", POLICIES)
+def test_schedule_stats_grid_rows_is_the_capacity(policy, kw):
+    T, k, E, M = 14, 6, 64, 32
+    idx = jnp.asarray(zipf_idx(T, k, E, 1.2))
+    sched = build_schedule(idx, E, M, policy=policy, **kw)
+    st = schedule_stats(sched)
+    assert int(st.grid_rows) == sched.capacity
+    assert int(st.padded_rows) <= int(st.grid_rows)
+
+
+def test_fixed_and_capacity_envelopes_unchanged():
+    idx = jnp.asarray(zipf_idx(14, 6, 64, 1.2))
+    assert build_schedule(idx, 64, 128, policy="fixed").capacity \
+        == schedule_capacity(14, 6, 64, 128) == 8320
+    cap = expert_capacity(14, 6, 64, 128, 1.25)
+    assert build_schedule(idx, 64, 128, policy="capacity_factor",
+                          capacity_factor=1.25).capacity == 64 * cap + 128
+
+
+def test_ep_microbatch_merge_sums_grid_rows():
+    def aux(padded, grid, useful):
+        return {"lb_loss": jnp.float32(1.0),
+                "sched/useful_rows": jnp.int32(useful),
+                "sched/dropped_rows": jnp.int32(0),
+                "sched/padded_rows": jnp.int32(padded),
+                "sched/n_blocks_active": jnp.int32(padded // 8),
+                "sched/grid_rows": jnp.int32(grid),
+                "sched/top1_share": jnp.float32(0.5),
+                "sched/occupancy": jnp.float32(0.0)}
+    out = _merge_chunk_aux([aux(64, 576, 40), aux(128, 704, 90)])
+    assert int(out["sched/grid_rows"]) == 576 + 704
+    assert int(out["sched/padded_rows"]) == 192
+    assert float(out["sched/occupancy"]) == pytest.approx(130 / 192)

@@ -131,9 +131,7 @@ def test_paged_decode_attention_compiles(one_chip, dtype):
     assert "tpu_custom_call" in hlo
 
 
-def test_moe_layer_pallas_executor_compiles(one_chip):
-    """One whole MoE layer (router -> permute -> fused gate/up -> grouped
-    GEMM -> unpermute, plus the shared experts) through ``apply_moe``."""
+def moe_layer_hlo(one_chip, n_tokens):
     params = jax.eval_shape(
         lambda: init_moe_params(jax.random.key(0), MOE, D))
     dcfg = dispatch_config(MOE, executor="pallas", schedule_policy="dynamic",
@@ -142,6 +140,18 @@ def test_moe_layer_pallas_executor_compiles(one_chip):
 
     def f(x, *leaves):
         return apply_moe(jax.tree.unflatten(tree, leaves), x, dcfg)[0]
-    hlo = compiled_text(one_chip, f, ((T, D), jnp.float32),
-                        *[(l.shape, l.dtype) for l in leaves])
-    assert hlo.count("tpu_custom_call") >= 5
+    return compiled_text(one_chip, f, ((n_tokens, D), jnp.float32),
+                         *[(l.shape, l.dtype) for l in leaves])
+
+
+def test_moe_layer_pallas_executor_compiles(one_chip):
+    """One whole MoE layer (router -> permute -> fused gate/up -> grouped
+    GEMM -> unpermute, plus the shared experts) through ``apply_moe``."""
+    assert moe_layer_hlo(one_chip, T).count("tpu_custom_call") >= 5
+
+
+@pytest.mark.parametrize("n_tokens", [1, 14, 32])
+def test_moe_layer_compiles_at_decode_token_counts(one_chip, n_tokens):
+    """The same layer at serving-step token counts, where the dynamic
+    envelope is a few hundred rows (64-row permute blocks at 14)."""
+    assert moe_layer_hlo(one_chip, n_tokens).count("tpu_custom_call") >= 5
