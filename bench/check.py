@@ -8,26 +8,32 @@ file, which also holds the readings each limit was set from):
   reference's best at its position; this is the widest gap by which a
   served token's reference logit lies below the reference's best logit
   there, the reference run once over each prompt and its served tokens.
-- ``kv_err_max``: the keys and values the timed path wrote into the paged
+- ``kv_err_max``: the cache rows the timed path wrote into the paged
   cache for each sampled prompt (its full blocks, found through the
-  prefix index), against the reference's: per layer, the largest
-  absolute difference over the largest reference magnitude; the worst
-  layer.  It reads every layer below the last, at every prompt position,
-  and so sees a change of precision that no greedy token shows.
+  prefix index; for grouped-KV attention the keys and values, each
+  architecture's ``cache_leaves``), against the reference's: per layer
+  and leaf, the largest absolute difference over the largest reference
+  magnitude; the worst of them.  It reads every layer below the last,
+  at every prompt position, and so sees a change of precision that no
+  greedy token shows.
 """
 from __future__ import annotations
 
 import numpy as np
 
 
-def program_kv(engine, prompt) -> list:
-    """Each layer's (K, V) that the engine wrote for the full blocks of
-    ``prompt``, read back from its paged pool: [(K, V)] with K, V of
-    shape (n, kv_heads, head_dim); empty when no block is cached."""
+def program_kv(c: dict, engine, prompt) -> list:
+    """Each layer's cache rows that the engine wrote for the full blocks
+    of ``prompt``, read back from its paged pool: one tuple a layer, of
+    the architecture's ``cache_leaves`` (for grouped-KV attention K and
+    V, each of shape (n, kv_heads, head_dim)); empty when no block is
+    cached."""
     import jax
     import jax.numpy as jnp
 
+    import archs
     from repro.serve.kv_cache import _chain_digest
+    leaves = archs.program(c["model_type"]).cache_leaves
     kv = engine.kv
     bs = kv.block_size
     prompt = np.asarray(prompt, np.int32)
@@ -42,22 +48,24 @@ def program_kv(engine, prompt) -> list:
         return []
     idx = jnp.asarray(blocks, jnp.int32)
     pools = kv.pools
-    layers = [(p["kv"]["k"][idx], p["kv"]["v"][idx])
+    layers = [tuple(p["kv"][n][idx] for n in leaves)
               for p in pools.get("prefix", [])]
     body = pools["body"]["b0"]["kv"]
-    layers += [(body["k"][g][idx], body["v"][g][idx])
-               for g in range(body["k"].shape[0])]
+    layers += [tuple(body[n][g][idx] for n in leaves)
+               for g in range(body[leaves[0]].shape[0])]
     out = jax.device_get(layers)
-    return [(k.reshape((-1,) + k.shape[2:]), v.reshape((-1,) + v.shape[2:]))
-            for k, v in out]
+    return [tuple(a.reshape((-1,) + a.shape[2:]) for a in layer)
+            for layer in out]
 
 
 def kv_err(got: list, ref: list, keep: np.ndarray) -> float:
-    """Worst layer's largest |got - ref| at the kept positions, over the
-    layer's largest |ref|."""
+    """Worst layer and leaf's largest |got - ref| at the kept positions,
+    over that leaf's largest |ref|.  Raises ``ValueError`` where the
+    program and the reference hold different numbers of layers or of
+    leaves a layer, so that no part of the cache goes unjudged."""
     worst = 0.0
-    for (gk, gv), (rk, rv) in zip(got, ref):
-        for g, r in ((gk, rk), (gv, rv)):
+    for got_layer, ref_layer in zip(got, ref, strict=True):
+        for g, r in zip(got_layer, ref_layer, strict=True):
             if keep.any():
                 worst = max(worst, float(np.max(np.abs(g - r)[keep])
                                          / np.max(np.abs(r))))

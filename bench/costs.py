@@ -5,28 +5,32 @@ kernel's count is what the algorithm needs for the call — the useful
 rows, each touched expert's weights read once — never what a padded or
 repeated schedule adds.  Sizes come from the configuration file (``c``)
 and the step's counts: ``T`` tokens in the step, ``useful`` routed rows
-and ``experts`` experts touched per MoE layer.
+and ``experts`` experts touched per MoE layer.  What differs between
+architectures (the keys that hold the sizes, the attention's FLOPs) is
+in each architecture's file (``archs.py``).
 """
 from __future__ import annotations
+
+import archs
 
 F32 = 4
 
 
 def dims(c: dict) -> dict:
-    """The sizes the formulas need, from a configuration file."""
-    if c["model_type"] == "deepseek_v3":
-        E, f = c["n_routed_experts"], c["moe_intermediate_size"]
-        shared = c["n_shared_experts"] * f
-        dense = c["first_k_dense_replace"]
-    else:
-        E, f = c["num_local_experts"], c["intermediate_size"]
-        shared, dense = 0, 0
-    return dict(d=c["hidden_size"], H=c["num_attention_heads"],
-                Hkv=c["num_key_value_heads"], D=c["head_dim"],
-                V=c["vocab_size"], E=E, k=c["num_experts_per_tok"], f=f,
-                shared=shared, f_dense=c["intermediate_size"],
-                dense_layers=dense,
-                moe_layers=c["num_hidden_layers"] - dense)
+    """The sizes the formulas need, from a configuration file: ``d``
+    model width, ``V`` vocabulary, ``E`` routed experts of width ``f``,
+    top ``k``, ``shared`` experts' summed width, ``f_dense`` the dense
+    FFN's width, ``dense_layers`` and ``moe_layers``."""
+    return archs.program(c["model_type"]).dims(c)
+
+
+def gqa_attention_flops(c: dict, context: float) -> float:
+    """One token's FLOPs in one layer of grouped-KV attention at
+    ``context`` positions: Q, K, V and output projections, scores and
+    values."""
+    d, H, Hkv, D = (c["hidden_size"], c["num_attention_heads"],
+                    c["num_key_value_heads"], c["head_dim"])
+    return 2.0 * d * (2 * H * D + 2 * Hkv * D) + 4.0 * H * D * context
 
 
 def expert_gemms(c: dict, useful: float, experts: float) -> tuple:
@@ -62,9 +66,9 @@ def token_flops(c: dict, context: float, head: bool) -> float:
     scores and values, the dense FFN, the router, its top-k experts and
     the shared experts) and, where it yields a token, the LM head."""
     m = dims(c)
-    d, H, Hkv, D = m["d"], m["H"], m["Hkv"], m["D"]
+    d = m["d"]
     layers = m["dense_layers"] + m["moe_layers"]
-    attn = 2.0 * d * (2 * H * D + 2 * Hkv * D) + 4.0 * H * D * context
+    attn = archs.program(c["model_type"]).attention_flops(c, context)
     dense = 2.0 * 3 * d * m["f_dense"]
     moe = 2.0 * (d * m["E"] + 3 * d * (m["k"] * m["f"] + m["shared"]))
     total = (layers * attn + m["dense_layers"] * dense

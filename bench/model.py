@@ -36,29 +36,21 @@ def rms_eps(c: dict) -> float:
 
 
 def model_config(c: dict):
-    """The program's ``ModelConfig`` for configuration file ``c``."""
-    from repro.configs.base import ModelConfig, MoEConfig
+    """The program's ``ModelConfig`` for configuration file ``c``, from
+    its architecture's file (``archs.py``)."""
+    import archs
     if rms_eps(c) != RMS_EPS_AS_RUN:
         raise ValueError(f"{c['name']}: the program's RMSNorm runs at eps "
                          f"{RMS_EPS_AS_RUN}, the file states {rms_eps(c)} "
                          "as run")
-    eng = c["engine"]
-    if c["model_type"] == "deepseek_v3":
-        moe = MoEConfig(
-            n_experts=c["n_routed_experts"], top_k=c["num_experts_per_tok"],
-            d_ff_expert=c["moe_intermediate_size"],
-            n_shared_experts=c["n_shared_experts"],
-            gating=c["scoring_func"], norm_topk=c["norm_topk_prob"],
-            routed_scale=c["routed_scaling_factor"],
-            first_dense_layers=c["first_k_dense_replace"],
-            d_ff_dense=c["intermediate_size"], block_m=eng["block_m"])
-    elif c["model_type"] == "mixtral":
-        moe = MoEConfig(
-            n_experts=c["num_local_experts"], top_k=c["num_experts_per_tok"],
-            d_ff_expert=c["intermediate_size"], gating="softmax",
-            norm_topk=True, block_m=eng["block_m"])
-    else:
-        raise ValueError(f"unknown model_type {c['model_type']!r}")
+    return archs.program(c["model_type"]).program_config(c)
+
+
+def gqa_config(c: dict, moe):
+    """The program's ``ModelConfig`` of a decoder with grouped-KV
+    attention (the configuration's heads, KV heads and ``head_dim``) and
+    ``moe`` for its MoE layers."""
+    from repro.configs.base import ModelConfig
     return ModelConfig(
         name=c["name"], family="moe", n_layers=c["num_hidden_layers"],
         d_model=c["hidden_size"], n_heads=c["num_attention_heads"],

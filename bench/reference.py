@@ -2,28 +2,33 @@
 
 It imports nothing of the program.  It reads the weights that the
 benchmark made (``model.make_weights``), laid out as the program's
-parameter tree, and follows the published architectures:
+parameter tree.  Each architecture's forward pass is in
+``ref/<model_type>.py`` (found through ``archs.py``) and follows its
+published description; it builds on the blocks kept here:
 
-- token embedding; each layer ``x += attn(rmsnorm(x))`` then
-  ``x += ffn(rmsnorm(x))``; a final RMSNorm and an untied LM head;
-- RMSNorm ``x / sqrt(mean(x^2) + eps) * w`` with the tree's ``scale``
-  holding ``w - 1``;
-- causal multi-head attention with grouped KV heads, rotate-half RoPE
-  with the configuration's ``rope_theta``, scores scaled by
-  ``head_dim ** -0.5``;
-- SwiGLU feed-forward ``(silu(x Wg) * x Wu) Wd``;
-- mixture of experts: router logits ``x R``; ``softmax`` or ``sigmoid``
-  scores; the top-k by score; weights renormalised over the k where the
+- ``_decoder``: token embedding; each layer ``x += attn(rmsnorm(x))``
+  then ``x += ffn(rmsnorm(x))``, a dense SwiGLU FFN in the leading
+  layers and the mixture of experts in the rest; a final RMSNorm (the
+  LM head is applied by ``Sequence``, untied);
+- ``_norm``: RMSNorm ``x / sqrt(mean(x^2) + eps) * w`` with the tree's
+  ``scale`` holding ``w - 1``;
+- ``_attention``: causal multi-head attention with grouped KV heads,
+  rotate-half RoPE (``_rope``) with the configuration's ``rope_theta``,
+  scores scaled by ``head_dim ** -0.5``;
+- ``_swiglu``: the feed-forward ``(silu(x Wg) * x Wu) Wd``;
+- ``_moe``: router logits ``x R``; ``softmax`` or ``sigmoid`` scores;
+  the top-k by score; weights renormalised over the k where the
   configuration says so, times ``routed_scaling_factor``; the routed
   SwiGLU experts, weighted and summed, plus the shared experts.
 
-Every matmul runs at full float32 precision.  The control (``ctl``) is
-the same computation one precision lower: each matmul operand split into
-a bfloat16 high part and a bfloat16 remainder, and the product of the
-two remainders dropped (three bfloat16 passes, what a TPU does for a
-float32 matmul at ``high``), on any backend.  A sequence is run whole,
-padded at its end to a multiple of ``PAD`` tokens so that few shapes
-compile; causal attention keeps the padding out of every real position.
+Every matmul (``_mm``, ``_ein``) runs at full float32 precision.  The
+control (``ctl``) is the same computation one precision lower: each
+matmul operand split into a bfloat16 high part and a bfloat16 remainder,
+and the product of the two remainders dropped (three bfloat16 passes,
+what a TPU does for a float32 matmul at ``high``), on any backend.  A
+sequence is run whole, padded at its end to a multiple of ``PAD`` tokens
+so that few shapes compile; causal attention keeps the padding out of
+every real position.
 """
 from __future__ import annotations
 
@@ -32,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import archs
 
 PAD = 512           # sequences are padded to a multiple of this
 ROWS = 256          # LM-head rows per block
@@ -128,39 +135,46 @@ def _moe(p, x, c, ctl):
 
 def _layer(p, x, c, kind, ctl):
     eps = c["rms_norm_eps"]
-    a, kv = _attention(p["attn"], _norm(x, p["norm1"]["scale"], eps), c,
-                       ctl)
+    a, rows = _attention(p["attn"], _norm(x, p["norm1"]["scale"], eps), c,
+                         ctl)
     x = x + a
     h = _norm(x, p["norm2"]["scale"], eps)
     if kind == "moe":
         y, margin = _moe(p["moe"], h, c, ctl)
-        return x + y, kv, margin
+        return x + y, rows, margin
     f = p["ffn"]
-    return (x + _swiglu(h, f["w_gate"], f["w_up"], f["w_down"], ctl), kv,
+    return (x + _swiglu(h, f["w_gate"], f["w_up"], f["w_down"], ctl), rows,
             jnp.full(x.shape[:1], jnp.inf))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _forward(params, tokens, cfg_items, ctl):
-    """Final hidden states, each layer's (K after RoPE, V), and per
-    position the smallest router margin over the MoE layers (the gap
-    between the k-th and the (k+1)-th expert's score)."""
-    c = dict(cfg_items)
+def _decoder(params, tokens, c, ctl):
+    """The program's decoder tree with grouped-KV attention
+    (``_attention``) and the mixture of experts (``_moe``), the forward
+    pass of an architecture file whose blocks are these: final hidden
+    states, each layer's cache rows (K after RoPE, V), and per position
+    the smallest router margin over the MoE layers (the gap between the
+    k-th and the (k+1)-th expert's score)."""
     x = params["embed"][tokens]
-    kvs = []
+    caches = []
     margin = jnp.full(tokens.shape, jnp.inf)
     for p in params.get("prefix", []):
-        x, kv, m = _layer(p, x, c, "dense", ctl)
-        kvs.append(kv)
+        x, rows, m = _layer(p, x, c, "dense", ctl)
+        caches.append(rows)
     body = params["body"]
     n = jax.tree.leaves(body)[0].shape[0]
     for g in range(n):
-        x, kv, m = _layer(jax.tree.map(lambda a: a[g], body["b0"]), x, c,
-                          "moe", ctl)
-        kvs.append(kv)
+        x, rows, m = _layer(jax.tree.map(lambda a: a[g], body["b0"]), x, c,
+                            "moe", ctl)
+        caches.append(rows)
         margin = jnp.minimum(margin, m)
-    return (_norm(x, params["final_norm"]["scale"], c["rms_norm_eps"]), kvs,
-            margin)
+    return (_norm(x, params["final_norm"]["scale"], c["rms_norm_eps"]),
+            caches, margin)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _forward(params, tokens, model_type, cfg_items, ctl):
+    return archs.reference(model_type).forward(params, tokens,
+                                               dict(cfg_items), ctl)
 
 
 @functools.partial(jax.jit, static_argnums=(3,))
@@ -180,13 +194,12 @@ def _head_rows(h, head, tokens, ctl):
     return tuple(o.reshape(S) for o in out)
 
 
-def _items(c: dict):
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "rope_theta", "rms_norm_eps", "num_experts_per_tok",
-            "norm_topk_prob", "routed_scaling_factor", "scoring_func")
-    # the epsilon as run, where the file states the program's departure
+def _items(c: dict, keys) -> tuple:
+    """The configuration's ``keys`` as a static argument of ``jit``,
+    each as run where the file states the program's departure
+    (``as_run``)."""
     as_run = c.get("as_run", {})
-    return tuple((k, as_run.get(k, c[k])) for k in keys if k in c)
+    return tuple((k, as_run.get(k, c[k])) for k in keys)
 
 
 class Sequence:
@@ -202,12 +215,14 @@ class Sequence:
         toks[:len(seq)] = seq
         self.first = len(prompt) - 1
         self.params, self.S = params, S
+        t = c["model_type"]
+        items = _items(c, archs.reference(t).KEYS)
         with jax.default_matmul_precision("highest"):
             self.h, self.kv, margin = _forward(
-                params, jnp.asarray(toks), _items(c), False)
+                params, jnp.asarray(toks), t, items, False)
             if control:
                 self.h_ctl, self.kv_ctl, _ = _forward(
-                    params, jnp.asarray(toks), _items(c), True)
+                    params, jnp.asarray(toks), t, items, True)
         self.margin = np.asarray(margin)
 
     def _rows(self, h, read, ctl):
@@ -242,6 +257,8 @@ class Sequence:
         return self.near_tie(tie)[self.first:self.first + len(self.served)]
 
     def prompt_kv(self, n: int, control=False) -> list:
-        """Each layer's (K, V) at positions [0, n), as numpy arrays."""
+        """Each layer's cache rows (for grouped-KV attention, K and V)
+        at positions [0, n), as numpy arrays."""
         kv = self.kv_ctl if control else self.kv
-        return jax.device_get([(k[:n], v[:n]) for k, v in kv])
+        return jax.device_get([tuple(a[:n] for a in layer)
+                               for layer in kv])

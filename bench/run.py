@@ -6,7 +6,9 @@ From the root of a checkout that holds ``BENCHMARK.json``, ``bench/``
 and the program (``src/repro``).  The cell names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); its per-layer metrics are readers
-``bench/metrics/<metric>.py``, each found by its name in the manifest.
+``bench/metrics/<metric>.py``, each found by its name in the manifest;
+the configuration's architecture is ``bench/arch/<model_type>.py`` and
+``bench/ref/<model_type>.py`` (``archs.py``).
 
 One run: make the weights from the seed on the device; build the paged
 serving engine; compile and run once every step shape the cell can use;
@@ -20,7 +22,8 @@ of the window and prints the cell's per-layer metrics instead of its
 end-to-end ones.
 
 Fails, printing no result, when JAX finds no TPU or fewer chips than the
-cell asks for, or when the program is not beside the benchmark.
+cell asks for, when the program is not beside the benchmark, or when no
+architecture's files bear the configuration's ``model_type``.
 """
 from __future__ import annotations
 
@@ -30,7 +33,6 @@ T_START = time.perf_counter()
 
 import argparse                                            # noqa: E402
 import gc                                                  # noqa: E402
-import importlib.util                                      # noqa: E402
 import json                                                # noqa: E402
 import os                                                  # noqa: E402
 import pathlib                                             # noqa: E402
@@ -79,11 +81,9 @@ def cell_of(man: dict, workload: str) -> dict:
 
 
 def reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", HERE / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    import archs
+    return archs.load_file(HERE / "metrics" / f"{name}.py",
+                           f"bench_metric_{name}").read
 
 
 def use_cache() -> str:
@@ -180,7 +180,7 @@ def sample(recs, rng, c: dict, engine) -> tuple:
                 tokens >= 2 * lim["min_tokens"]
                 and rows >= 2 * lim["min_kv_rows"]):
             break
-        kv = check.program_kv(engine, r.prompt)
+        kv = check.program_kv(c, engine, r.prompt)
         if tokens >= 2 * lim["min_tokens"] and not kv:
             continue
         picked.append(r)
@@ -205,6 +205,12 @@ def main() -> None:
     cell = found["cell"]
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(HERE))
+    import archs
+    import model
+    try:
+        archs.require(model.load(found["config_file"])["model_type"])
+    except archs.UnknownArchitecture as e:
+        fail(str(e))
     # the TPU runtime's own logs would go to a fixed path outside the
     # checkout
     os.environ.setdefault("TPU_LOG_DIR", "disabled")

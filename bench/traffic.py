@@ -16,6 +16,16 @@ one mean gap before 0, so the window opens on an engine already under
 the mix's load.  Each part has its own fixed multiset of gaps and lengths,
 so the window holds the same work for every seed.
 
+Order (optional ``order`` block of the mix): with ``strata`` k, each
+multiset is split into k equal strata by size, and every k consecutive
+requests of a part hold one value of each stratum (its own draw for the
+prompt lengths, the answer lengths and the gaps), so no stretch of a
+run piles up long prompts or short gaps; with ``lead_in_order_seed``,
+the lead-in's lengths and gaps come in the order drawn from that number
+for every run, so every seed opens its window on the same load (the
+token ids are still the run's own).  Without the block, plain
+permutations.
+
 Length distributions: ``lognormal`` with ``median``, ``sigma`` (of the
 log), clipped to ``[min, max]``; ``uniform`` over ``[min, max]``.
 """
@@ -62,13 +72,31 @@ def count(mix: dict, seconds: float) -> int:
     return max(1, int(round(mix["arrivals"]["rate_per_s"] * seconds)))
 
 
-def part(mix: dict, n: int, start, rng, vocab: int) -> list:
+def arrange(x: np.ndarray, strata: int, rng) -> np.ndarray:
+    """x (ascending) in an order drawn from ``rng`` in which every run of
+    ``strata`` consecutive values holds one value of each of ``strata``
+    equal parts of x; with one stratum, a plain permutation."""
+    if strata <= 1:
+        return rng.permutation(x)
+    parts = [rng.permutation(s) for s in np.array_split(x, strata)]
+    out = []
+    for j in range(len(parts[0])):
+        out.extend(rng.permutation([s[j] for s in parts if j < len(s)]))
+    return np.asarray(out, dtype=x.dtype)
+
+
+def part(mix: dict, n: int, start, rng, vocab: int,
+         order_rng=None) -> list:
     """n requests at the mix's rate, due from ``start`` on; with ``start``
-    None, the last is due one mean gap before 0."""
-    p_len = rng.permutation(lengths(mix["prompt_tokens"], n))
-    o_len = rng.permutation(lengths(mix["output_tokens"], n))
+    None, the last is due one mean gap before 0.  The order of lengths
+    and gaps is drawn from ``order_rng`` where given, the token ids from
+    ``rng``."""
+    o = rng if order_rng is None else order_rng
+    k = mix.get("order", {}).get("strata", 1)
+    p_len = arrange(lengths(mix["prompt_tokens"], n), k, o)
+    o_len = arrange(lengths(mix["output_tokens"], n), k, o)
     gaps = -np.log1p(-quantiles(n)) / mix["arrivals"]["rate_per_s"]
-    due = np.cumsum(rng.permutation(gaps))
+    due = np.cumsum(arrange(gaps, k, o))
     due += start if start is not None else (
         -due[-1] - 1.0 / mix["arrivals"]["rate_per_s"])
     return [Request(float(due[i]),
@@ -99,5 +127,7 @@ def generate(mix: dict, *, seed: int, seconds: float,
     lead = float(mix["arrivals"].get("lead_in_s", 0))
     reqs = []
     if lead > 0:
-        reqs = part(mix, count(mix, lead), None, rng, vocab)
+        fixed = mix.get("order", {}).get("lead_in_order_seed")
+        reqs = part(mix, count(mix, lead), None, rng, vocab,
+                    None if fixed is None else np.random.default_rng(fixed))
     return reqs + part(mix, count(mix, seconds), 0.0, rng, vocab)

@@ -68,3 +68,20 @@ def test_too_few_candidates_leave_the_run_incorrect(cfg):
     out = check.readings(cfg, None, [req(8, 3)], [kv(8)])
     assert out["checked_tokens"] == 2
     assert not check.verdict(cfg, out)[0]
+
+
+def layer(n_leaves, n=4):
+    return tuple(np.ones((n, 1, 1)) for _ in range(n_leaves))
+
+
+@pytest.mark.parametrize("got", [
+    [layer(1), layer(1)],               # a leaf a layer left unread
+    [layer(3), layer(3)],               # a leaf the reference lacks
+    [layer(2)],                         # a layer left unread
+    [layer(2), layer(2), layer(2)],     # a layer the reference lacks
+])
+def test_kv_err_judges_every_layer_and_leaf_or_raises(got):
+    ref = [layer(2), layer(2)]
+    assert check.kv_err([layer(2), layer(2)], ref, np.ones(4, bool)) == 0.0
+    with pytest.raises(ValueError):
+        check.kv_err(got, ref, np.ones(4, bool))

@@ -7,10 +7,10 @@ import json
 import pytest
 
 import control
-from bench_kit import tiny_cell
+from bench_kit import ARCHS, tiny_cell
 
 
-@pytest.mark.parametrize("model_type", ["deepseek_v3", "mixtral"])
+@pytest.mark.parametrize("model_type", ARCHS)
 def test_control_fails_where_the_program_passes(tmp_path, model_type):
     found = tiny_cell(tmp_path, model_type)
     c = json.loads(found["config_file"].read_text())

@@ -16,7 +16,7 @@ import pytest
 
 import repro.serve.engine as engine_mod
 import run
-from bench_kit import CPU_PEAK, CpuDevice, run_args, tiny_cell
+from bench_kit import ARCHS, CPU_PEAK, CpuDevice, run_args, tiny_cell
 
 
 def broken(fault):
@@ -40,7 +40,7 @@ def broken(fault):
     return make_broken
 
 
-@pytest.mark.parametrize("model_type", ["deepseek_v3", "mixtral"])
+@pytest.mark.parametrize("model_type", ARCHS)
 @pytest.mark.parametrize("fault", [None, "state_unchanged", "half_batch",
                                    "token_altered"])
 def test_fault_makes_the_run_incorrect(tmp_path, monkeypatch, model_type,
